@@ -6,12 +6,10 @@ that matter are the ref-path times (XLA CPU) and, on real hardware, the
 Mosaic-compiled kernels.  Reported for completeness + regression tracking.
 
 The SIZE SWEEP section (1e5 -> 4e6 rows, REPRO_BENCH_SWEEP_MAX tunable)
-captures the scaling curve the chunked-cumsum compaction and the
-diagonal-partitioned merge unlock: stream compaction + compaction-merge
-rows at multi-million-row stores — sizes the old (block, block) one-hot
-scatter and both-tables-VMEM-resident merge could not express on real
-hardware (64 MB cube / >16 MB key residency).  ``kernels/sweep/scale_ok``
-gates on the sweep actually reaching >= 2e6 rows.
+captures the scaling curve of stream compaction and the compaction-merge
+(device merge + tombstone compaction) at multi-million-row stores.
+``kernels/sweep/scale_ok`` gates on the sweep actually reaching >= 2e6
+rows.
 """
 from __future__ import annotations
 
@@ -71,10 +69,10 @@ def _sweep(emit, timeit):
              rows_per_s=int(n / max(t, 1e-9)))
         ran = n
 
-    # block-size effect at a fixed size: the old 512 ceiling vs 4096 tiles
+    # block-size effect at a fixed size: the smallest tile vs 4096 tiles
     n = min(400_000, max_n)
     mask = jnp.asarray(rng.random(n) < 0.1)
-    for blk in (512, ops.LARGE_BLOCK):
+    for blk in (ops.auto_block(0), ops.LARGE_BLOCK):
         t, _ = timeit(lambda: ops.compact_indices(mask, 1 << 15, block=blk),
                       repeats=2)
         emit(f"kernels/sweep/stream_compact_block{blk}", t, n=n, block=blk)

@@ -66,10 +66,8 @@ def main():
     # rewrite-mode dual-branch pass count: (?x rdf:type Person) entails
     # through BOTH domain- and range-entailing properties, so the pattern
     # needs a subject-binding AND an object-binding compaction over the
-    # same store.  The fused member-compaction kernel resolves both in
-    # ONE pass with the member/domain/range id sets resident on-chip; the
-    # trace-time counters pin it (per-source: 1 member pass, 0 mask-based
-    # passes, where the pre-fusion plan materialized full-store masks).
+    # same store.  The dual-mask compaction kernel resolves both in ONE
+    # pass; the trace-time counters pin it (per source: 1 dual pass).
     from repro.kernels import ops as _kops
 
     dual_q = [Pattern("?x", "rdf:type", "Person")]
@@ -78,18 +76,17 @@ def main():
     # cold plan below re-traces every pass it actually makes
     _kops.compact_indices.clear_cache()
     _kops.dual_compact_indices.clear_cache()
-    _kops.rewrite_member_compact.clear_cache()
     _kops.reset_pass_counters()
     eng_rw.run(dual_q)
-    member_passes = _kops.pass_counters["member_compact"]
+    dual_passes = _kops.pass_counters["dual_compact"]
     # one residual single-mask pass belongs to DISTINCT's dedup compaction,
     # not the pattern; the pattern itself must trace zero single passes
     # (it used to trace two — one per branch)
     single_passes = _kops.pass_counters["compact"]
     t_dual, _ = timeit(lambda: eng_rw.run(dual_q), repeats=3)
     emit("table6/rewrite_dual_branch", t_dual,
-         member_passes=member_passes, single_passes=single_passes,
-         passed=bool(member_passes >= 1 and single_passes <= 1))
+         dual_passes=dual_passes, single_passes=single_passes,
+         passed=bool(dual_passes >= 1 and single_passes <= 1))
 
     # live-overlay cost: Q1 against an uncompacted ~1% delta (two-source
     # gathers over base + device-resident delta bucket) vs post-compaction

@@ -33,6 +33,9 @@ def main() -> None:
                     help="machine-readable artifact path ('' disables)")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_abox, bench_kernels, bench_materialize, bench_queries,
         bench_serving, bench_tbox, bench_updates,

@@ -50,23 +50,13 @@ def main() -> int:
     assert jax.device_count() == nglobal, (jax.device_count(), nglobal)
     assert jax.local_device_count() == args.local_devices
 
-    # 1. cross-process collective over the GLOBAL mesh: the exchange fabric.
-    # jax 0.4.x's CPU backend has no multiprocess collectives (0.5+ routes
-    # them through gloo) — degrade to a topology-only check there so the
-    # smoke still validates the runtime wiring on old pins.
-    try:
-        out = jax.pmap(lambda x: jax.lax.psum(x, "i"), axis_name="i")(
-            jnp.ones((jax.local_device_count(),), jnp.int32))
-        assert int(np.asarray(out)[0]) == nglobal, np.asarray(out)
-        print(f"[proc {args.process_id}] collective OK: psum={int(out[0])} "
-              f"over {nglobal} devices / {args.num_processes} processes",
-              flush=True)
-    except Exception as e:  # pragma: no cover - backend-dependent
-        if "aren't implemented" not in str(e):
-            raise
-        print(f"[proc {args.process_id}] collective SKIPPED "
-              f"(CPU backend lacks multiprocess collectives): {e}",
-              flush=True)
+    # 1. cross-process collective over the GLOBAL mesh: the exchange fabric
+    out = jax.pmap(lambda x: jax.lax.psum(x, "i"), axis_name="i")(
+        jnp.ones((jax.local_device_count(),), jnp.int32))
+    assert int(np.asarray(out)[0]) == nglobal, np.asarray(out)
+    print(f"[proc {args.process_id}] collective OK: psum={int(out[0])} "
+          f"over {nglobal} devices / {args.num_processes} processes",
+          flush=True)
 
     # 2. repartition-join parity over this process's local devices
     from repro.core.engine import KnowledgeBase, PAPER_QUERIES

@@ -33,8 +33,7 @@ view after a mutation is O(delta), not O(base):
 
 ``compact()`` (driven by core/engine.py) folds a delta into its base with
 one sorted-merge pass per materialized permutation.  The device path runs
-the merge-path Pallas kernel (kernels/merge_sorted.py) over the resident
-buffers and drops tombstones with the stream-compaction kernel, so the
+the device merge (``ops.merge_gather``) over the resident buffers and drops tombstones with the stream-compaction kernel, so the
 merged store is assembled on the accelerator; the host only pulls the
 final array once to mirror it into the new StoreIndex's search keys.
 """
@@ -322,7 +321,11 @@ class DeviceStoreCache:
             alive_h = (view.base_alive_h if key == "scan"
                        else view.base_alive_h[view.base_index.perm(key).perm])
             self._stat("upload_base_alive_rows", view.base_n)
-            base_alive = jnp.asarray(alive_h)
+            # the kill scatter donates this mask, so it must be a buffer
+            # XLA allocated: on the CPU backend ``jnp.asarray`` wraps a
+            # 64-byte-aligned host array in place, and a donated foreign
+            # buffer is copied instead of updated in place
+            base_alive = jnp.asarray(alive_h).copy()
         delta, dalive = self._upload_delta(view, key, cap)
         return _DevState(
             base_token=token, base_alive=base_alive,
@@ -723,8 +726,8 @@ def compact_view(view: StoreView, device: bool = False):
     tombstones are dropped during the merge.  The other permutations stay
     lazy in the new index and re-sort on first use.
 
-    ``device=True`` runs the merge on the accelerator: the merge-path
-    Pallas kernel computes the interleave over the resident [base | delta]
+    ``device=True`` runs the merge on the accelerator: ``ops.merge_gather``
+    computes the interleave over the resident [base | delta]
     buffers, the stream-compaction kernel drops tombstones, and the merged
     store is materialized by device gathers — bit-identical to the host
     path (pinned by tests), with the host only pulling the finished array

@@ -100,7 +100,7 @@ def build_local_dictionary(hi, lo, valid, base):
     """
     hi = jnp.where(valid, hi, SENTINEL)
     lo = jnp.where(valid, lo, SENTINEL)
-    hi_s, lo_s, _ = pair64.sort_pairs(hi, lo)
+    hi_s, lo_s = lax.sort((hi, lo), num_keys=2, is_stable=False)
     valid_s = hi_s != SENTINEL
     uniq = pair64.unique_mask_sorted(hi_s, lo_s) & valid_s
     ranks = jnp.cumsum(uniq.astype(jnp.int32)) - 1  # dup rows share their head's rank
@@ -185,7 +185,7 @@ def sharded_dictionary_fn(axis_name: str, n_shards: int, bin_cap: int, base: int
         rlo = recv_lo.reshape(-1)
 
         # 2. local unique + global exclusive scan of counts (paper step 2)
-        rhi_s, rlo_s, _ = pair64.sort_pairs(rhi, rlo)
+        rhi_s, rlo_s = lax.sort((rhi, rlo), num_keys=2, is_stable=False)
         valid_s = rhi_s != SENTINEL
         uniq = pair64.unique_mask_sorted(rhi_s, rlo_s) & valid_s
         local_count = uniq.astype(jnp.int32).sum()

@@ -29,9 +29,11 @@ is no manual invalidation step.
 """
 from __future__ import annotations
 
+import contextvars
 import threading
 import time
 from dataclasses import dataclass, field
+from functools import partial
 
 import numpy as np
 
@@ -55,6 +57,7 @@ from repro.obs import trace as obs_trace
 from repro.obs.metrics import REGISTRY
 from repro.rdf.generator import RawDataset
 from repro.testing import faults
+from repro.utils.parallel import run_concurrently
 
 # The paper's appendix queries (over the LUBM vocabulary).
 PAPER_QUERIES = {
@@ -112,16 +115,17 @@ class KnowledgeBase:
         tbox = tbox or build_tbox(raw.onto, parallel=parallel_tbox)
         kb = encode_obe(raw, tbox)
         dtb = DeviceTBox.build(tbox)
-        lite, lvalid, lstats = lite_materialize(kb, dtb)
-        full, fvalid, fstats = full_materialize(kb, dtb)
-        return cls(
-            kb=kb,
-            dtb=dtb,
-            lite_spo=compact_rows(lite, lvalid),
-            full_spo=compact_rows(full, fvalid),
-            lite_stats=lstats,
-            full_stats=fstats,
-        )
+
+        def derive(materialize):
+            rows, valid, stats = materialize(kb, dtb)
+            return compact_rows(rows, valid), stats
+
+        # the two stores derive from threads so their compiles overlap
+        (lite, lstats), (full, fstats) = run_concurrently(
+            [partial(derive, lite_materialize),
+             partial(derive, full_materialize)])
+        return cls(kb=kb, dtb=dtb, lite_spo=lite, full_spo=full,
+                   lite_stats=lstats, full_stats=fstats)
 
     # -- store plumbing ------------------------------------------------------
     def _base_store(self, mode: str) -> jnp.ndarray:
@@ -161,29 +165,46 @@ class KnowledgeBase:
         of them is appended, so a failure mid-derivation (fault site
         ``engine.flush_mat``) leaves the log and cursor untouched — the
         published store stays consistent and a later flush simply retries
-        the whole backlog.
+        the whole backlog.  The modes derive concurrently (their compiles
+        overlap) and commit in order: a fault in one mode leaves the modes
+        before it committed, as a mode-by-mode flush would.
         """
         n = len(self._pending_raw)
+        todo, fault = [], None
         for mode in modes:
             cur = self._mat_cursor[mode]
             if cur >= n:
                 continue
+            try:
+                for b in range(cur, n):
+                    faults.fire("engine.flush_mat", mode=mode, batch=b)
+            except Exception as e:  # the modes before this one still land
+                fault = e
+                break
+            todo.append((mode, cur))
+
+        def derive(mode, cur):
+            t0 = time.perf_counter()
             with obs_trace.span("flush_mat", mode=mode, n_batches=n - cur):
-                t0 = time.perf_counter()
-                derived = []
-                for spo in self._pending_raw[cur:]:
-                    faults.fire("engine.flush_mat", mode=mode,
-                                batch=cur + len(derived))
-                    derived.append(
-                        materialize_delta_mode(spo, self.dtb, mode))
-                for rows in derived:
-                    self.delta.log(mode).append(rows)
-                    self.mat_counts[mode] += 1
-                self._mat_cursor[mode] = n
-                REGISTRY.histogram("engine/flush_s", mode=mode).observe(
-                    time.perf_counter() - t0)
-                REGISTRY.counter("engine/derived_rows", mode=mode).inc(
-                    sum(int(r.shape[0]) for r in derived))
+                rows = [materialize_delta_mode(spo, self.dtb, mode)
+                        for spo in self._pending_raw[cur:]]
+            return rows, time.perf_counter() - t0
+
+        # each thread derives inside a copy of this context: its span joins
+        # the calling request's trace
+        results = run_concurrently([
+            partial(contextvars.copy_context().run, derive, m, c)
+            for m, c in todo])
+        for (mode, _), (derived, secs) in zip(todo, results):
+            for rows in derived:
+                self.delta.log(mode).append(rows)
+                self.mat_counts[mode] += 1
+            self._mat_cursor[mode] = n
+            REGISTRY.histogram("engine/flush_s", mode=mode).observe(secs)
+            REGISTRY.counter("engine/derived_rows", mode=mode).inc(
+                sum(int(r.shape[0]) for r in derived))
+        if fault is not None:
+            raise fault
         if self._pending_raw and all(
                 c >= n for c in self._mat_cursor.values()):
             self._pending_raw.clear()
@@ -251,14 +272,17 @@ class KnowledgeBase:
         return {tuple(r) for r in rows.tolist()}
 
     def prewarm(self, queries=None, modes=("litemat",), buckets=(),
-                use_index: bool = True) -> int:
-        """Pre-trace executables for ``queries`` (default: Q1–Q4)."""
+                use_index: bool = True, selects=None) -> int:
+        """Pre-trace executables for ``queries`` (default: Q1–Q4) in every
+        mode, compiling them concurrently; returns #plans compiled.
+        ``selects`` gives each query's projection (default: all vars)."""
         queries = (list(queries) if queries is not None
                    else list(PAPER_QUERIES.values()))
-        return sum(
-            self.engine(m, use_index).prewarm(queries, buckets=buckets)
-            for m in modes
-        )
+        engines = [self.engine(m, use_index) for m in modes]
+        before = sum(e.cache_stats["misses"] for e in engines)
+        run_concurrently([c for e in engines for c in e.prewarm_calls(
+            queries, buckets=buckets, selects=selects)])
+        return sum(e.cache_stats["misses"] for e in engines) - before
 
     def warm_device(self, mode: str = "litemat", keys=("scan", "pos")):
         """Bring ``mode``'s device buffers up to the current version.
@@ -511,10 +535,11 @@ class KnowledgeBase:
 
             # re-derive the affected instances from their live raw triples
             frontier = self.live_raw_mentions(inst)
-            for mode in ("litemat", "full"):
-                derived = materialize_delta_mode(frontier, self.dtb, mode)
-                self.append_derived(
-                    mode, derived[mentions_mask(derived, inst)])
+            derived = run_concurrently([
+                partial(materialize_delta_mode, frontier, self.dtb, mode)
+                for mode in ("litemat", "full")])
+            for mode, rows in zip(("litemat", "full"), derived):
+                self.append_derived(mode, rows[mentions_mask(rows, inst)])
             self._bump()
             REGISTRY.counter("engine/deleted_rows").inc(
                 int(deleted.shape[0]))
@@ -538,9 +563,9 @@ class KnowledgeBase:
         lazily on first use).  Dictionary growth needs no work: new terms
         were absorbed into ``kb.tables`` at insert time.
 
-        ``device`` selects the merge implementation: the merge-path Pallas
-        kernel over the resident device buffers (bit-identical to the host
-        merge; default on TPU backends) or the host searchsorted interleave
+        ``device`` selects the merge implementation: the device merge
+        (``ops.merge_gather``) over the resident device buffers
+        (bit-identical to the host merge; default on TPU backends) or the host searchsorted interleave
         (default elsewhere, where 'device' arrays live in host RAM anyway).
         """
         with self.write_lock:
@@ -553,8 +578,10 @@ class KnowledgeBase:
                 if device is None:
                     device = jax.default_backend() == "tpu"
                 sizes = {}
-                for mode in MODES:
-                    dev, idx = compact_view(self.view(mode), device=device)
+                views = [self.view(mode) for mode in MODES]
+                merged = run_concurrently([  # the stores' compiles overlap
+                    partial(compact_view, v, device=device) for v in views])
+                for mode, (dev, idx) in zip(MODES, merged):
                     if mode == "rewrite":
                         self.kb.spo = dev
                     elif mode == "litemat":

@@ -38,6 +38,7 @@ request is two binary searches + a slice rather than a full-view sort.
 from __future__ import annotations
 
 import itertools
+import threading
 
 from dataclasses import dataclass, field
 
@@ -123,6 +124,8 @@ class StoreIndex:
     _h: np.ndarray = field(repr=False)  # host copy of the store
     _perms: dict = field(default_factory=dict, repr=False)
     token: int = field(default_factory=lambda: next(_TOKENS), repr=False)
+    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False,
+                                  compare=False)
 
     @classmethod
     def build(cls, spo) -> "StoreIndex":
@@ -150,17 +153,20 @@ class StoreIndex:
         return idx
 
     def perm(self, name: str) -> _Perm:
-        if name not in self._perms:
-            a, b, c = _ORDERS[name]
-            h = self._h
-            p = np.lexsort((h[:, c], h[:, b], h[:, a]))
-            hp = h[p]
-            self._perms[name] = _Perm(
-                rows=jnp.asarray(hp),
-                primary=np.ascontiguousarray(hp[:, a]),
-                key=_composite(hp[:, a], hp[:, b]),
-                perm=p,
-            )
+        if name in self._perms:
+            return self._perms[name]
+        with self._lock:  # concurrent planners sort and upload it once
+            if name not in self._perms:
+                a, b, c = _ORDERS[name]
+                h = self._h
+                p = np.lexsort((h[:, c], h[:, b], h[:, a]))
+                hp = h[p]
+                self._perms[name] = _Perm(
+                    rows=jnp.asarray(hp),
+                    primary=np.ascontiguousarray(hp[:, a]),
+                    key=_composite(hp[:, a], hp[:, b]),
+                    perm=p,
+                )
         return self._perms[name]
 
     def inv_perm(self, name: str) -> np.ndarray:

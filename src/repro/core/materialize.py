@@ -23,6 +23,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.tbox import TBox
 from repro.utils import pair64
@@ -124,35 +125,49 @@ def concept_bounds(dtb: DeviceTBox, concept_ids):
 # ---------------------------------------------------------------------------
 
 
+def table_columns(table, rows):
+    """``table[rows]`` of a small int32[K, W] TBox table, as W 1-D columns.
+
+    One gather per column: a single 2-D gather would come out [len(rows),
+    W] in a row-major TPU layout, each row padded to 128 lanes — the
+    closure's candidate arrays then need 128/W times their size in HBM.
+    """
+    return [table[:, k][rows] for k in range(table.shape[1])]
+
+
 def candidate_types(spo, dtb: DeviceTBox):
     """(instance, concept, explicit) candidate rows, INVALID-padded.
 
-    Row layout (static): N explicit + N*Kd domain + N*Kr range candidates.
+    Row layout (static): N explicit rows, then N per domain slot (Kd), then
+    N per range slot (Kr).
     """
     s, p, o = spo[:, 0], spo[:, 1], spo[:, 2]
     is_type = p == dtb.rdf_type_id
 
-    inst_e = jnp.where(is_type, s, INVALID)
-    conc_e = jnp.where(is_type, o, INVALID)
-
     pos = jnp.searchsorted(dtb.dr_prop_ids, p)
     pos = jnp.clip(pos, 0, dtb.dr_prop_ids.shape[0] - 1)
     p_hit = (dtb.dr_prop_ids[pos] == p) & (~is_type)
-    doms = dtb.domain_table[pos]  # (N, Kd)
-    rngs = dtb.range_table[pos]  # (N, Kr)
-    dom_ok = p_hit[:, None] & (doms >= 0)
-    rng_ok = p_hit[:, None] & (rngs >= 0)
-    inst_d = jnp.where(dom_ok, s[:, None], INVALID).reshape(-1)
-    conc_d = jnp.where(dom_ok, doms, INVALID).reshape(-1)
-    inst_r = jnp.where(rng_ok, o[:, None], INVALID).reshape(-1)
-    conc_r = jnp.where(rng_ok, rngs, INVALID).reshape(-1)
-
-    inst = jnp.concatenate([inst_e, inst_d, inst_r])
-    conc = jnp.concatenate([conc_e, conc_d, conc_r])
+    inst = [jnp.where(is_type, s, INVALID)]
+    conc = [jnp.where(is_type, o, INVALID)]
+    for col, bound in ((s, table_columns(dtb.domain_table, pos)),
+                       (o, table_columns(dtb.range_table, pos))):
+        for c in bound:
+            ok = p_hit & (c >= 0)
+            inst.append(jnp.where(ok, col, INVALID))
+            conc.append(jnp.where(ok, c, INVALID))
     explicit = jnp.concatenate(
-        [is_type, jnp.zeros(inst_d.shape, bool), jnp.zeros(inst_r.shape, bool)]
-    )
-    return inst, conc, explicit
+        [is_type, jnp.zeros((s.shape[0] * (len(inst) - 1),), bool)])
+    return jnp.concatenate(inst), jnp.concatenate(conc), explicit
+
+
+def sort_rows(*cols):
+    """Sort parallel int32 columns lexicographically (first column major).
+
+    Every column is a key, so equal rows are identical and stability is
+    moot: the unstable sort gives the same arrays as ``jnp.lexsort`` and
+    compiles in about half the time on a TPU.
+    """
+    return lax.sort(tuple(cols), num_keys=len(cols), is_stable=False)
 
 
 def msc_select(inst, conc, explicit, dtb: DeviceTBox):
@@ -163,8 +178,9 @@ def msc_select(inst, conc, explicit, dtb: DeviceTBox):
     """
     # sort by (instance, concept, explicit-first) so duplicate heads carry
     # explicitness; INVALID rows sink to the end.
-    perm = jnp.lexsort(((~explicit).astype(jnp.int32), conc, inst))
-    inst_s, conc_s, expl_s = inst[perm], conc[perm], explicit[perm]
+    inst_s, conc_s, implicit_s = sort_rows(
+        inst, conc, (~explicit).astype(jnp.int32))
+    expl_s = implicit_s == 0
     valid = inst_s != INVALID
 
     first = jnp.concatenate(
@@ -188,12 +204,12 @@ def msc_select(inst, conc, explicit, dtb: DeviceTBox):
     # some candidate of the same instance lies in one of c's spill ranges.
     S = dtb.concept_spill_lo.shape[1]
     _, cpos, chit = concept_bounds(dtb, conc_s)
-    sp_lo = jnp.where(chit[:, None], dtb.concept_spill_lo[cpos], 0)
-    sp_hi = jnp.where(chit[:, None], dtb.concept_spill_hi[cpos], 0)
     any_spill_hit = jnp.zeros(conc_s.shape, bool)
     if S > 0:
-        for k in range(S):
-            lo_k, hi_k = sp_lo[:, k], sp_hi[:, k]
+        for lo_k, hi_k in zip(table_columns(dtb.concept_spill_lo, cpos),
+                              table_columns(dtb.concept_spill_hi, cpos)):
+            lo_k = jnp.where(chit, lo_k, 0)
+            hi_k = jnp.where(chit, hi_k, 0)
             has = lo_k < hi_k
             L = pair64.searchsorted_pair(inst_s, conc_s, inst_s, lo_k, side="left")
             R = pair64.searchsorted_pair(inst_s, conc_s, inst_s, hi_k, side="left")
@@ -241,8 +257,12 @@ def lite_materialize(kb, dtb: DeviceTBox | None = None):
     return out, valid, {k: int(v) for k, v in stats.items()}
 
 
+@partial(jax.jit, static_argnames="n")
+def _take_valid(rows, valid, n: int):
+    idx = jnp.nonzero(valid, size=n)[0]
+    return jnp.stack([rows[:, c][idx] for c in range(rows.shape[1])], axis=1)
+
+
 def compact_rows(rows, valid):
-    """Drop padding rows (host sync for the final count)."""
-    order = jnp.argsort(~valid, stable=True)
-    n = int(valid.sum())
-    return rows[order][:n]
+    """Drop padding rows, keeping order (host sync for the final count)."""
+    return _take_valid(rows, valid, int(valid.sum()))

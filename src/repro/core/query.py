@@ -56,13 +56,16 @@ in ascending-cardinality order, which also gives capacity estimates.
 from __future__ import annotations
 
 import hashlib
+import threading
 import time
 from dataclasses import dataclass, field
+from functools import partial
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.abox import EncodedKB
 from repro.core.delta import StoreView
@@ -71,8 +74,10 @@ from repro.core.materialize import DeviceTBox
 from repro.kernels import ops
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import REGISTRY
+from repro.utils.parallel import run_concurrently
 
 INVALID = jnp.int32(np.iinfo(np.int32).max)
+_EXEC_LOCK = threading.Lock()  # plan-cache check-and-insert
 _I32_MIN = int(np.iinfo(np.int32).min)
 _I32_MAX = int(np.iinfo(np.int32).max)
 
@@ -359,39 +364,21 @@ def _rewrite_type_bindings(sig: PatternSig, ds, dyn, cap: int):
     Subject-binding rows (explicit/domain) and object-binding rows (range)
     are compacted INDEPENDENTLY per source and their bound values stitched:
     a row entailing the target through both branches yields two bindings.
-    Both branches' member-set predicates are fused INTO the compaction
-    kernel (``ops.rewrite_member_compact``): the sorted id sets stay
-    on-chip and each tile resolves its own membership tests, so the
-    full-store boolean masks the old ``_in_set`` path materialized before
-    compacting no longer exist (``_type_rewrite_masks_dyn`` survives only
-    for the planner's counting pass).
+    With a range branch, both masks compact in ONE dual-mask kernel pass
+    per source.
     """
     _, _, has_dom, has_rng = sig.extra_caps
-    mem, tid = dyn["o"], dyn["tid"]
-    dom, rng = dyn["dom"], dyn["rng"]
-    base_n = ds.base.shape[0]
-    out_b = ops.rewrite_member_compact(
-        ds.base, ds.base_alive, tid, mem, dom, rng, cap, has_dom, has_rng,
-        block=ops.auto_block(base_n))
-    out_d = None
+    sets = (dyn["o"], dyn["tid"], dyn["dom"], dyn["rng"], has_dom, has_rng)
+    ms_b, mo_b = _type_rewrite_masks_dyn(ds.base, ds.base_alive, *sets)
+    ms_d = mo_d = None
     if ds.delta is not None:
-        out_d = ops.rewrite_member_compact(
-            ds.delta, ds.delta_alive, tid, mem, dom, rng, cap, has_dom,
-            has_rng, block=ops.auto_block(ds.delta.shape[0]))
+        ms_d, mo_d = _type_rewrite_masks_dyn(ds.delta, ds.delta_alive, *sets)
     if not has_rng:  # no object branch: the subject stream is the answer
-        take_s, ok_s, total_s = out_b
-        if out_d is not None:
-            take_s, ok_s, total_s = _stitch_compact(
-                out_b[0], out_b[2], out_d[0], out_d[2], base_n, cap)
+        take_s, ok_s, total_s = _masked_compact_both(ds, ms_b, ms_d, cap)
         vals_s = ops.two_source_gather(ds.base, ds.delta, take_s)[:, 0]
         return ok_s, total_s, vals_s
-    take_s, ok_s, total_s = out_b[0:3]
-    take_o, total_o = out_b[3], out_b[5]
-    if out_d is not None:
-        take_s, ok_s, total_s = _stitch_compact(
-            out_b[0], out_b[2], out_d[0], out_d[2], base_n, cap)
-        take_o, _, total_o = _stitch_compact(
-            out_b[3], out_b[5], out_d[3], out_d[5], base_n, cap)
+    (take_s, ok_s, total_s), (take_o, _, total_o) = _dual_masked_compact_both(
+        ds, ms_b, mo_b, ms_d, mo_d, cap)
     vals_s = ops.two_source_gather(ds.base, ds.delta, take_s)[:, 0]
     vals_o = ops.two_source_gather(ds.base, ds.delta, take_o)[:, 2]
     j = jnp.arange(cap, dtype=jnp.int32)
@@ -455,14 +442,6 @@ def _eval_pattern(sig: PatternSig, cap: int, stores, dyn):
                            cap), total
 
 
-# Above this many rows, INL probes take the windowed pair search (the
-# merge-path-partitioned reuse in kernels/ops.py) instead of the resident
-# kernel whose table planes must fit in VMEM — the last whole-table VMEM
-# residency in the query path, now a dispatch bound instead of a planner
-# disqualifier.
-INL_RESIDENT_MAX = 1 << 20
-
-
 def _inl_ranges(ds, prim: int, sec: int, qhi, qlo, valid):
     """Probe one source's key planes -> (starts, lens), all pids batched.
 
@@ -472,15 +451,12 @@ def _inl_ranges(ds, prim: int, sec: int, qhi, qlo, valid):
     (pid, key + 1).  ``qhi``/``qlo``/``valid`` carry ALL pid groups
     concatenated (k probes per pid), so one source costs exactly two
     pair-search launches regardless of how many pids are probed.
-    Invalid probe rows get zero-length ranges.  Tables past
-    ``INL_RESIDENT_MAX`` rows probe through the windowed (merge-path
-    partitioned) search — O(block) VMEM at any table size.
+    Invalid probe rows get zero-length ranges.  The search reads the
+    table in place, so any table size probes the same way.
     """
     t_hi, t_lo = ds[:, prim], ds[:, sec]
-    search = (ops.pair_search_windowed if ds.shape[0] > INL_RESIDENT_MAX
-              else ops.pair_search)
-    starts = search(t_hi, t_lo, qhi, qlo)
-    ends = search(t_hi, t_lo, qhi, qlo + 1)
+    starts = ops.pair_search(t_hi, t_lo, qhi, qlo)
+    ends = ops.pair_search(t_hi, t_lo, qhi, qlo + 1)
     lens = jnp.where(valid, jnp.maximum(ends - starts, 0), 0)
     return starts, lens
 
@@ -615,7 +591,7 @@ def join(a: Relation, b: Relation, cap: int, a_sorted: bool = False) -> Relation
 
     ``a_sorted=True`` asserts the build side already sits in ascending
     ``shared[0]`` order with invalid rows last (the shard combine produces
-    exactly that via the partitioned-merge kernel), skipping the argsort.
+    exactly that via the device merge), skipping the argsort.
     """
     shared = [v for v in a.vars if v in b.vars]
     if not shared:
@@ -627,9 +603,11 @@ def join(a: Relation, b: Relation, cap: int, a_sorted: bool = False) -> Relation
     if a_sorted:
         a_cols, ka_s = a.cols, ka
     else:
-        aperm = jnp.argsort(ka)
+        # unstable: rows of equal key may land in any order, which moves
+        # rows within the join output but never changes its set
+        ka_s, aperm = lax.sort((ka, lax.iota(jnp.int32, ka.shape[0])),
+                               num_keys=1, is_stable=False)
         a_cols = a.cols[:, aperm]
-        ka_s = ka[aperm]
 
     kb_ = jnp.where(b.valid, b.col(key), INVALID)
     L = jnp.searchsorted(ka_s, kb_, side="left")
@@ -665,9 +643,10 @@ def join(a: Relation, b: Relation, cap: int, a_sorted: bool = False) -> Relation
 def distinct(rel: Relation, select: tuple, cap: int) -> Relation:
     """Project onto ``select`` vars and deduplicate rows."""
     cols = [jnp.where(rel.valid, rel.col(v), INVALID) for v in select]
-    perm = jnp.lexsort(tuple(reversed(cols)))
-    cols = [c[perm] for c in cols]
-    valid = rel.valid[perm]
+    # every selected column is a key: equal rows are identical, so the
+    # unstable sort yields the lexsort's arrays
+    cols = lax.sort(tuple(cols), num_keys=len(cols), is_stable=False)
+    valid = cols[0] != INVALID
     neq = jnp.zeros(valid.shape[0] - 1, dtype=bool)
     for c in cols:
         neq = neq | (c[1:] != c[:-1])
@@ -969,23 +948,27 @@ class QueryEngine:
 
         return wrapper
 
+    def _memo(self, key, slabel: str, kind: str, make):
+        """Plan-cache lookup; ``make()`` builds the jitted function on a
+        miss.  Atomic across threads, so concurrent planners of one key
+        share one executable (jit compiles on first call, not here)."""
+        with _EXEC_LOCK:
+            fn = self._exec_cache.get(key)
+            hit = fn is not None
+            if not hit:
+                fn = self._timed_compile(jax.jit(make()), slabel, kind)
+                self._exec_cache[key] = fn
+            self.cache_stats["hits" if hit else "misses"] += 1
+        event = ("hit" if hit else "miss") + ("_batch" if kind == "batch"
+                                               else "")
+        REGISTRY.counter("query/plan_cache", event=event, sig=slabel).inc()
+        return fn
+
     def _executable(self, key, sigs, caps, join_cap: int, select):
         """Memoized jitted plan: signature + buckets -> compiled function."""
-        fn = self._exec_cache.get(key)
-        slabel = sig_label(sigs)
-        if fn is None:
-            self.cache_stats["misses"] += 1
-            REGISTRY.counter("query/plan_cache", event="miss",
-                             sig=slabel).inc()
-            fn = self._timed_compile(
-                jax.jit(self._make_run_device(sigs, caps, join_cap, select)),
-                slabel, "solo")
-            self._exec_cache[key] = fn
-        else:
-            self.cache_stats["hits"] += 1
-            REGISTRY.counter("query/plan_cache", event="hit",
-                             sig=slabel).inc()
-        return fn
+        return self._memo(key, sig_label(sigs), "solo",
+                          lambda: self._make_run_device(sigs, caps, join_cap,
+                                                        select))
 
     def _batch_executable(self, key, sigs, caps, join_cap: int, select):
         """Memoized VMAPPED plan: one dispatch answers a whole request batch.
@@ -996,23 +979,10 @@ class QueryEngine:
         path, pair search) lifts through ``jax.vmap``, so a batch of B
         same-signature requests costs ONE XLA dispatch instead of B.
         """
-        fn = self._exec_cache.get(key)
-        slabel = sig_label(sigs)
-        if fn is None:
-            self.cache_stats["misses"] += 1
-            REGISTRY.counter("query/plan_cache", event="miss_batch",
-                             sig=slabel).inc()
-            fn = self._timed_compile(
-                jax.jit(jax.vmap(
-                    self._make_run_device(sigs, caps, join_cap, select),
-                    in_axes=(None, 0))),
-                slabel, "batch")
-            self._exec_cache[key] = fn
-        else:
-            self.cache_stats["hits"] += 1
-            REGISTRY.counter("query/plan_cache", event="hit_batch",
-                             sig=slabel).inc()
-        return fn
+        return self._memo(key, sig_label(sigs), "batch",
+                          lambda: jax.vmap(self._make_run_device(
+                              sigs, caps, join_cap, select),
+                              in_axes=(None, 0)))
 
     @staticmethod
     def _bucket(n: int) -> int:
@@ -1462,27 +1432,56 @@ class QueryEngine:
             "hot_keys": hot_keys,
         }
 
+    def prewarm_calls(self, queries, buckets=(), selects=None) -> list:
+        """One zero-argument call per query that compiles its executables.
+
+        A call runs its query until the plan stops changing (the first
+        run's observed selectivities can re-plan it, see :meth:`_warm`),
+        so the requests that follow find every executable compiled.  Each
+        floor in ``buckets`` adds a call that runs the natural plan once
+        with its caps raised to at least that floor, covering the bucket
+        sizes the store will grow into.  Calls block until done.
+        ``selects`` gives each query's projection (default: all vars).
+        """
+        calls = []
+        for i, pats in enumerate(queries):
+            select = selects[i] if selects is not None else None
+            calls.append(partial(self._warm, pats, select))
+            if not buckets:
+                continue
+            sigs, dyns, caps, join_cap, sel, stores = \
+                self._plan(pats, select)[:6]
+            for b in sorted({self._bucket(int(b)) for b in buckets}):
+                cs = tuple(max(c, b) for c in caps)
+                jc = max(join_cap, b)
+                fn = self._executable(("exec", self.mode, sigs, cs, jc, sel),
+                                      sigs, cs, jc, sel)
+                calls.append(partial(_run_blocking, fn, stores, dyns))
+        return calls
+
+    def _warm(self, patterns, select, max_plans: int = 3) -> None:
+        """Run a query, re-planned after each run, until a plan repeats."""
+        seen = set()
+        for _ in range(max_plans):
+            planned = self._plan(patterns, select)
+            key = (planned[0], tuple(planned[2]), planned[3], planned[4])
+            if key in seen:
+                return
+            seen.add(key)
+            self._run_planned(planned)
+
     def prewarm(self, queries, buckets=(), select=None) -> int:
         """Pre-trace executables for a query set; returns #plans compiled.
 
-        Each query is compiled at its *natural* capacity buckets (what
-        ``run`` would pick against the current store) and additionally at
-        every floor in ``buckets``: caps are raised to at least the floor,
-        covering the bucket sizes the store will grow into.  Subsequent
+        The calls of :meth:`prewarm_calls` run concurrently.  Subsequent
         ``run`` calls whose buckets land on a prewarmed combination skip
         the trace+compile cold start entirely.
         """
         before = self.cache_stats["misses"]
-        for pats in queries:
-            sigs, dyns, caps, join_cap, sel, stores = \
-                self._plan(pats, select)[:6]
-            capsets = {(tuple(caps), join_cap)}
-            for b in buckets:
-                b = self._bucket(int(b))
-                capsets.add((tuple(max(c, b) for c in caps),
-                             max(join_cap, b)))
-            for cs, jc in sorted(capsets):
-                key = ("exec", self.mode, sigs, cs, jc, sel)
-                fn = self._executable(key, sigs, cs, jc, sel)
-                jax.block_until_ready(fn(stores, dyns))
+        run_concurrently(self.prewarm_calls(
+            queries, buckets, [select] * len(queries)))
         return self.cache_stats["misses"] - before
+
+
+def _run_blocking(fn, *args):
+    return jax.block_until_ready(fn(*args))

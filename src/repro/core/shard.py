@@ -71,7 +71,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.abox import EncodedKB, encode_obe, tbox_term_map
 from repro.core.closure import full_materialize
@@ -96,31 +96,27 @@ from repro.obs.metrics import REGISTRY
 from repro.testing import faults
 from repro.testing.faults import FaultCrash, FaultError
 from repro.utils import pair64
-from repro.utils.jaxcompat import make_mesh, shard_map
 
 _EMPTY = np.zeros((0, 3), dtype=np.int32)
 _HASH_MULT = np.uint64(0x9E3779B1)  # Fibonacci multiplicative hash
 
 # failures the stacked shard_map path treats as "device down, fall back to
 # the per-shard dispatch loop": injected transients + XLA runtime errors
-try:
-    from jax.errors import JaxRuntimeError as _JaxRuntimeError
-    _DEVICE_FAILURES = (FaultError, _JaxRuntimeError)
-except ImportError:  # older jax: no public runtime-error class
-    _DEVICE_FAILURES = (FaultError,)
+_DEVICE_FAILURES = (FaultError, jax.errors.JaxRuntimeError)
 
 
 def _local_mesh(n_shards: int, axis_name: str):
     """A 1-D mesh over this PROCESS's addressable devices.
 
-    Single-process runtimes see every device, so this is `make_mesh`
+    Single-process runtimes see every device, so this is `jax.make_mesh`
     verbatim there; under `jax.distributed` each process's stores live on
     its local devices only, and a mesh built from the global device list
     would try to address remote buffers.  (Cross-process global-mesh
     sharding is the remaining ROADMAP item-2 step.)
     """
     if jax.process_count() == 1:
-        return make_mesh((n_shards,), (axis_name,))
+        return jax.make_mesh((n_shards,), (axis_name,),
+                             axis_types=(jax.sharding.AxisType.Auto,))
     devs = jax.local_devices()[:n_shards]
     return jax.sharding.Mesh(np.asarray(devs), (axis_name,))
 
@@ -397,7 +393,7 @@ class ShardedKB:
             body = sharded_dictionary_fn("d", self.n_shards, cap, base=0)
             mesh = _local_mesh(self.n_shards, "d")
             d = P("d")
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 body, mesh=mesh, in_specs=(d, d, d),
                 out_specs=sharded_out_specs(), check_vma=False))
             self._enc_cache[cap] = fn
@@ -797,7 +793,7 @@ def _merge_tree(runs: list, key_col: int):
     log2(k) merge levels instead of a left-deep fold: the accumulated run
     is never re-merged against every remaining part, so each row moves
     O(log k) times rather than O(k).  Each level pairs neighbours through
-    ``ops.merge_gather`` (the partitioned-merge kernel) + one row gather;
+    ``ops.merge_gather`` (the device merge) + one row gather;
     INVALID keys sort last, so padded rows sink to the fold's tail.
     Shared by the host-fallback combine and the device repartition join's
     shard-local fold of exchanged partitions.
@@ -913,10 +909,14 @@ class ShardStack:
     with work independent of the base sizes: delta buckets re-upload
     O(n_shards * delta cap) rows, base tombstones land as point scatters,
     and base slabs re-upload only when a shard's base token changes
-    (compaction) or the common pow2 capacity grows.
+    (compaction) or the common pow2 capacity grows.  Every stack is placed
+    with ``sharding`` (leading axis split over the shard mesh), so shard
+    i's slab sits on shard i's device and the shard_map executable reads
+    it in place.
     """
 
-    def __init__(self):
+    def __init__(self, sharding):
+        self.sharding = sharding
         self._states: dict = {}
         self._lock = threading.RLock()  # same contract as DeviceStoreCache
         self.stats = {"base_rebuilds": 0, "upload_base_rows": 0,
@@ -936,6 +936,9 @@ class ShardStack:
                     out.append(("alive", id(st["dalive"]),
                                 st["dalive"].nbytes))
         return out
+
+    def _put(self, x):
+        return jax.device_put(x, self.sharding)
 
     def _base_host(self, view, key):
         if key == "scan":
@@ -974,7 +977,7 @@ class ShardStack:
                 REGISTRY.counter("device/transfer_bytes",
                                  src="shard_stack").inc(int(h.nbytes))
             st = {"ncap": ncap, "tokens": tokens,
-                  "base": jnp.asarray(base), "alive": jnp.asarray(alive),
+                  "base": self._put(base), "alive": self._put(alive),
                   "n_kills": [len(v.kills) for v in views],
                   "dcap": -1, "delta": None, "dalive": None,
                   "dstate": [None] * S}
@@ -988,9 +991,9 @@ class ShardStack:
                     pad = _pow2(idx.shape[0])
                     full = np.full(pad, ncap, np.int64)
                     full[:idx.shape[0]] = idx
-                    st["alive"] = st["alive"].at[
+                    st["alive"] = self._put(st["alive"].at[
                         i, jnp.asarray(full.astype(np.int32))].set(
-                        False, mode="drop")
+                        False, mode="drop"))
                     self.stats["kill_scatter_rows"] += int(idx.shape[0])
                     REGISTRY.counter("device/kill_scatter_rows",
                                      src="shard_stack").inc(int(idx.shape[0]))
@@ -1015,8 +1018,8 @@ class ShardStack:
                                      kind="delta").inc(dcap)
                     REGISTRY.counter("device/transfer_bytes",
                                      src="shard_stack").inc(dcap * 12)
-                st["delta"] = jnp.asarray(drows)
-                st["dalive"] = jnp.asarray(dalive)
+                st["delta"] = self._put(drows)
+                st["dalive"] = self._put(dalive)
             st["dcap"] = dcap
             st["dstate"] = dstate
         return DevStore(base=st["base"], base_alive=st["alive"],
@@ -1037,7 +1040,7 @@ class ShardedQueryEngine:
     shard (per-shard sigs must agree; capacities unify to the max), else a
     per-shard dispatch loop (async across devices).  Cross-group joins
     all-gather the per-shard relations, fold them key-sorted with the
-    partitioned-merge kernel, and finish with the ordinary sort-merge join
+    device merge, and finish with the ordinary sort-merge join
     + distinct — bit-identical to the single-store engine.
     """
 
@@ -1218,9 +1221,15 @@ class ShardedQueryEngine:
             join_cap *= 2
         raise RuntimeError("sharded query kept overflowing its buckets")
 
+    def _shard_mesh(self):
+        if self._mesh is None:
+            self._mesh = _local_mesh(self.skb.n_shards, "shard")
+        return self._mesh
+
     def _stack(self, key: str) -> ShardStack:
         if key not in self._stacks:
-            self._stacks[key] = ShardStack()
+            self._stacks[key] = ShardStack(
+                NamedSharding(self._shard_mesh(), P("shard")))
         return self._stacks[key]
 
     def _sm_executable(self, sigs, caps, join_cap, sel, has_delta):
@@ -1234,8 +1243,6 @@ class ShardedQueryEngine:
             return fn
         self.cache_stats["misses"] += 1
         REGISTRY.counter("shard/exec_cache", event="miss").inc()
-        if self._mesh is None:
-            self._mesh = _local_mesh(self.skb.n_shards, "shard")
 
         def body(stores, dyns):
             st1 = {k: DevStore(
@@ -1255,10 +1262,10 @@ class ShardedQueryEngine:
             out = distinct(rel, sel, join_cap)
             return out.cols[None], out.valid[None], out.overflow[None]
 
-        f = shard_map(body, mesh=self._mesh,
-                      in_specs=(P("shard"), P("shard")),
-                      out_specs=(P("shard"), P("shard"), P("shard")),
-                      check_vma=False)
+        f = jax.shard_map(body, mesh=self._shard_mesh(),
+                          in_specs=(P("shard"), P("shard")),
+                          out_specs=(P("shard"), P("shard"), P("shard")),
+                          check_vma=False)
         fn = jax.jit(f)
         self._exec_cache[key] = fn
         return fn
@@ -1322,9 +1329,6 @@ class ShardedQueryEngine:
             return out.cols, out.valid, out.overflow
 
         if self._shard_map_on():
-            if self._mesh is None:
-                self._mesh = _local_mesh(S, "shard")
-
             def body(ac, av, rc, rv):
                 abins = _bin_by_key(ac[0], av[0], ai, S)
                 rbins = _bin_by_key(rc[0], rv[0], ri, S)
@@ -1333,9 +1337,9 @@ class ShardedQueryEngine:
                 cols, valid, ovf = local_join(arecv, rrecv)
                 return cols[None], valid[None], ovf[None]
 
-            f = shard_map(body, mesh=self._mesh,
-                          in_specs=(P("shard"),) * 4,
-                          out_specs=(P("shard"),) * 3, check_vma=False)
+            f = jax.shard_map(body, mesh=self._shard_mesh(),
+                              in_specs=(P("shard"),) * 4,
+                              out_specs=(P("shard"),) * 3, check_vma=False)
         else:
             def f(ac, av, rc, rv):
                 abins = jnp.stack(
@@ -1371,16 +1375,13 @@ class ShardedQueryEngine:
             return out.cols, out.valid
 
         if self._shard_map_on():
-            if self._mesh is None:
-                self._mesh = _local_mesh(S, "shard")
-
             def body(c, v):
                 oc, ov = local(c[0], v[0])
                 return oc[None], ov[None]
 
-            f = shard_map(body, mesh=self._mesh,
-                          in_specs=(P("shard"),) * 2,
-                          out_specs=(P("shard"),) * 2, check_vma=False)
+            f = jax.shard_map(body, mesh=self._shard_mesh(),
+                              in_specs=(P("shard"),) * 2,
+                              out_specs=(P("shard"),) * 2, check_vma=False)
         else:
             def f(c, v):
                 outs = [local(c[i], v[i]) for i in range(S)]

@@ -43,6 +43,7 @@ from repro.core.query import QueryEngine
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import MetricsRegistry
 from repro.testing import faults
+from repro.utils.parallel import run_concurrently
 
 
 def _is_sharded(kb) -> bool:
@@ -564,16 +565,28 @@ class SnapshotRegistry:
         with self._lock:
             return sorted(v for v, s in self._snaps.items() if s.refs > 0)
 
-    def prewarm(self, queries=None, modes=None) -> None:
-        """Compile the plan caches once so serving pays no cold starts."""
+    def prewarm(self, queries=None, modes=None, selects=None) -> None:
+        """Compile the plan caches once so serving pays no cold starts.
+
+        On a single store every (mode, query) executable compiles
+        concurrently.  ``selects`` gives each query's projection, as the
+        requests that follow will ask for it (default: all its variables).
+        """
         from repro.core.engine import PAPER_QUERIES
 
         queries = (list(queries) if queries is not None
                    else list(PAPER_QUERIES.values()))
+        selects = selects if selects is not None else [None] * len(queries)
         with self.pin() as pin:
-            for mode in (modes or self.modes):
-                for q in queries:
-                    pin.query(q, mode=mode)
+            if pin.snapshot.sharded:
+                for mode in (modes or self.modes):
+                    for q, sel in zip(queries, selects):
+                        pin.query(q, select=sel, mode=mode)
+                return
+            run_concurrently([
+                c for mode in (modes or self.modes)
+                for c in pin.snapshot.engine(mode).prewarm_calls(
+                    queries, selects=selects)])
 
 
 __all__ = ["Snapshot", "SnapshotRegistry", "Pin"]

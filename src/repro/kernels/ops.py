@@ -1,9 +1,11 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""Public jit'd wrappers for the Pallas kernels and the device-side ops.
 
-Each wrapper pads inputs to kernel block multiples, dispatches to the Pallas
-implementation (interpret mode on CPU — the kernels TARGET TPU; interpret
-executes the same kernel body for validation), slices padding off, and
-matches the corresponding ``ref.py`` oracle exactly.
+Each kernel wrapper pads inputs to kernel block multiples, dispatches to the
+Pallas implementation (interpret mode on CPU — the kernels TARGET TPU;
+interpret executes the same kernel body for validation), slices padding
+off, and matches the corresponding ``ref.py`` oracle exactly.  The
+searches (``pair_search``, ``merge_gather``) are plain XLA on every
+backend: they gather per lane, which Mosaic cannot lower.
 """
 from __future__ import annotations
 
@@ -18,16 +20,12 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.closure_expand import closure_expand_pallas
 from repro.kernels.interval_filter import interval_filter_pallas
-from repro.kernels.merge_sorted import (
-    merge_path_pallas, merge_path_partitioned_pallas,
-)
 from repro.kernels.msc_select import msc_select_pallas
-from repro.kernels.pair_search import pair_search_pallas
 from repro.kernels.stream_compact import (
-    dual_compact_pallas, interval_compact_pallas,
-    masked_interval_compact_pallas, member_compact_pallas,
-    stream_compact_pallas,
+    MIN_BLOCK, dual_compact_pallas, interval_compact_pallas,
+    masked_interval_compact_pallas, stream_compact_pallas,
 )
+from repro.utils import pair64
 
 INVALID = np.int32(np.iinfo(np.int32).max)
 
@@ -43,8 +41,7 @@ INVALID = np.int32(np.iinfo(np.int32).max)
 # lock guards the dict's read-modify-write, and each bump is mirrored into
 # the process metrics registry (kernels/passes{kind=...}) where the obs
 # exporters read it.  The dict itself stays the public read surface.
-pass_counters = {"compact": 0, "dual_compact": 0, "member_compact": 0,
-                 "merge_resident": 0, "merge_partitioned": 0}
+pass_counters = {"compact": 0, "dual_compact": 0, "merge": 0}
 _PASS_LOCK = threading.Lock()
 
 
@@ -66,20 +63,21 @@ def reset_pass_counters() -> dict:
 
 
 def _interpret() -> bool:
+    """Interpret mode off the TPU: CPU runs execute the same kernel bodies
+    through the Pallas interpreter; on a TPU every kernel compiles."""
     return jax.default_backend() != "tpu"
 
 
-# Block-size selection for the compaction kernels.  The chunked-cumsum
-# body's VMEM is O(chunk^2) regardless of block, so large stores take
-# 4096-row tiles (8x fewer grid steps + stitch segments than the old
-# 512 ceiling); small stores keep small tiles so padding stays bounded.
+# Block-size selection for the compaction kernels.  Large stores take
+# 4096-row tiles (fewer grid steps and stitch segments); small stores take
+# the smallest TPU tile, one (8, 128) int32 vreg, so padding stays bounded.
 LARGE_BLOCK = 4096
 _LARGE_N = 1 << 16
 
 
 def auto_block(n: int) -> int:
     """Compaction tile size for an n-row store (static at trace time)."""
-    return LARGE_BLOCK if n >= _LARGE_N else 512
+    return LARGE_BLOCK if n >= _LARGE_N else MIN_BLOCK
 
 
 def _pad1(x, m, fill):
@@ -123,54 +121,22 @@ def closure_expand(conc, sorted_ids, anc_table, block: int = 1024):
     return out[:n]
 
 
-@partial(jax.jit, static_argnames=("block",))
-def pair_search(table_hi, table_lo, qhi, qlo, block: int = 1024):
-    """Lexicographic binary search (left); -> int32 positions."""
-    n = qhi.shape[0]
-    if table_hi.shape[0] == 0:  # empty table: every query lands at 0
-        return jnp.zeros((n,), jnp.int32)
-    mx = np.int32(np.iinfo(np.int32).max)
-    ph = _pad1(qhi, block, mx)
-    pl_ = _pad1(qlo, block, mx)
-    out = pair_search_pallas(table_hi, table_lo, ph, pl_, block=block,
-                             interpret=_interpret())
-    return out[:n]
+@jax.jit
+def pair_search(table_hi, table_lo, qhi, qlo):
+    """Lexicographic binary search (left) -> int32 positions.
 
-
-@partial(jax.jit, static_argnames=("block",))
-def pair_search_windowed(table_hi, table_lo, qhi, qlo, block: int = 1024):
-    """Lexicographic binary search with NO whole-table VMEM residency.
-
-    ``pair_search`` keeps both table planes VMEM-resident (constant index
-    maps) — fine up to ~1M rows, the ceiling that used to disqualify the
-    index-nested-loop join on large stores.  This path re-expresses the
-    batch search as a stable merge, reusing the diagonal-partitioned
-    merge-path kernel: sort the queries (the probe side is small), merge
-    the sorted query run against the table run (per-tile DMA'd windows,
-    O(block) VMEM at any table size), and read each query's position off
-    its merge slot — query rank ``r`` landing at merged slot ``i`` has
-    exactly ``i - r`` table keys before it.  Ties keep queries before
-    equal table keys (run A first), so positions match the 'left' contract
-    of ``pair_search`` / ``ref.ref_pair_search`` bit-exactly.
+    Plain XLA (``pair64.searchsorted_pair``): the table stays in HBM and
+    every step is one vectorized gather, so there is no table-size ceiling.
+    Mosaic has no per-lane gather from a VMEM ref, which a Pallas version
+    of this search would need.
     """
-    n = qhi.shape[0]
-    perm = jnp.lexsort((qlo, qhi))
-    qh_s, ql_s = qhi[perm], qlo[perm]
-    pad = max(block - n, 0)  # static: >= block queries forces the
-    if pad:  # partitioned dispatch whenever the table reaches block too
-        qh_s = jnp.concatenate([qh_s, jnp.full((pad,), INVALID, jnp.int32)])
-        ql_s = jnp.concatenate([ql_s, jnp.full((pad,), INVALID, jnp.int32)])
-    nq = n + pad
-    g = merge_gather(qh_s, ql_s, table_hi, table_lo, block=block)
-    idx = jnp.arange(g.shape[0], dtype=jnp.int32)
-    slots = jnp.zeros((nq,), jnp.int32).at[
-        jnp.where(g < nq, g, nq)].set(idx, mode="drop")
-    pos = slots - jnp.arange(nq, dtype=jnp.int32)
-    return jnp.zeros((n,), jnp.int32).at[perm].set(pos[:n])
+    if table_hi.shape[0] == 0:  # empty table: every query lands at 0
+        return jnp.zeros(qhi.shape, jnp.int32)
+    return pair64.searchsorted_pair(table_hi, table_lo, qhi, qlo)
 
 
-@partial(jax.jit, static_argnames=("block",))
-def merge_gather(a_hi, a_lo, b_hi, b_lo, block: int = 1024):
+@jax.jit
+def merge_gather(a_hi, a_lo, b_hi, b_lo):
     """Stable-merge gather map of two lex-sorted (hi, lo) pair runs.
 
     Returns int32[n + m]: values < n select run A, values >= n select
@@ -179,26 +145,22 @@ def merge_gather(a_hi, a_lo, b_hi, b_lo, block: int = 1024):
     the host.  Ties keep A-before-B order (the ``index.merge_sorted``
     contract; ``ref.ref_merge_sorted`` is the oracle).
 
-    Dispatch: when BOTH runs reach ``block`` rows the diagonal-partitioned
-    kernel runs (per-tile DMA windows, O(block) VMEM — no ceiling on n+m);
-    smaller runs take the resident kernel, whose whole-table VMEM footprint
-    is then trivially affordable.
+    B row j lands at slot ``j + #{A <= B[j]}`` (one binary search per B
+    row); every other slot takes the next A row in order, read off a
+    prefix count of the B slots — O(m log n + n + m) in plain XLA.
     """
     n, m = a_hi.shape[0], b_hi.shape[0]
     if m == 0:
         return jnp.arange(n, dtype=jnp.int32)
     if n == 0:
         return jnp.arange(m, dtype=jnp.int32)
-    if n >= block and m >= block:
-        _bump_pass("merge_partitioned")
-        out = merge_path_partitioned_pallas(a_hi, a_lo, b_hi, b_lo,
-                                            block=block,
-                                            interpret=_interpret())
-    else:
-        _bump_pass("merge_resident")
-        out = merge_path_pallas(a_hi, a_lo, b_hi, b_lo, block=block,
-                                interpret=_interpret())
-    return out[: n + m]
+    _bump_pass("merge")
+    slot_b = (pair64.searchsorted_pair(a_hi, a_lo, b_hi, b_lo, side="right")
+              + jnp.arange(m, dtype=jnp.int32))
+    is_b = jnp.zeros((n + m,), jnp.int32).at[slot_b].set(1)
+    b_upto = jnp.cumsum(is_b)  # B rows in slots [0, i]
+    i = jnp.arange(n + m, dtype=jnp.int32)
+    return jnp.where(is_b == 1, n + b_upto - 1, i - b_upto)
 
 
 def two_source_gather(base, delta, idx):
@@ -259,7 +221,7 @@ def _assemble_compact(local, counts, cap: int, block: int):
 
 
 @partial(jax.jit, static_argnames=("cap", "block"))
-def compact_indices(mask, cap: int, block: int = 512):
+def compact_indices(mask, cap: int, block: int = MIN_BLOCK):
     """Stable compaction of an arbitrary bool mask.
 
     Returns (take int32[cap] — indices of the first cap True positions,
@@ -273,7 +235,8 @@ def compact_indices(mask, cap: int, block: int = 512):
 
 
 @partial(jax.jit, static_argnames=("cap", "block"))
-def dual_compact_indices(mask_a, mask_b, cap: int, block: int = 512):
+def dual_compact_indices(mask_a, mask_b, cap: int,
+                         block: int = MIN_BLOCK):
     """Stable compaction of TWO bool masks over the same rows in ONE pass.
 
     Returns (take_a, ok_a, total_a, take_b, ok_b, total_b) — each triple
@@ -291,40 +254,8 @@ def dual_compact_indices(mask_a, mask_b, cap: int, block: int = 512):
             *_assemble_compact(lb, cb, cap, block))
 
 
-@partial(jax.jit, static_argnames=("cap", "block", "has_dom", "has_rng"))
-def rewrite_member_compact(spo, alive, tid, mem, dom, rng, cap: int,
-                           has_dom: bool, has_rng: bool, block: int = 512):
-    """Fused rewrite-mode type-pattern member-set masks + compaction.
-
-    One kernel pass over ``spo`` evaluates the full RDFS reformulation of
-    ``(?x rdf:type C)`` — subject branch ``(p == tid & o ∈ mem) | p ∈ dom``
-    and object branch ``p ∈ rng`` — with the sorted id sets resident
-    on-chip, and compacts the matching row indices in the same pass: the
-    full-store boolean masks the old ``_in_set`` path materialized before
-    compaction never exist.  Returns ``(take_s, ok_s, total_s)``, extended
-    with ``(take_o, ok_o, total_o)`` when ``has_rng``; each triple matches
-    the ``compact_indices`` contract.  ``has_dom``/``has_rng`` are static,
-    so absent branches compile to nothing.
-    """
-    _bump_pass("member_compact")
-    s = _pad1(spo[:, 0], block, INVALID)
-    p = _pad1(spo[:, 1], block, INVALID)
-    o = _pad1(spo[:, 2], block, INVALID)
-    pa = _pad1(alive.astype(jnp.int32), block, np.int32(0))
-    params = jnp.stack([tid]).astype(jnp.int32)
-    outs = member_compact_pallas(
-        params, mem, dom, rng, s, p, o, pa, has_dom=has_dom,
-        has_rng=has_rng, block=block, interpret=_interpret())
-    if has_rng:
-        ls, cs, lo_, co = outs
-        return (*_assemble_compact(ls, cs, cap, block),
-                *_assemble_compact(lo_, co, cap, block))
-    ls, cs = outs
-    return _assemble_compact(ls, cs, cap, block)
-
-
 @partial(jax.jit, static_argnames=("cap", "block"))
-def interval_compact(p, o, params, cap: int, block: int = 512):
+def interval_compact(p, o, params, cap: int, block: int = MIN_BLOCK):
     """Fused LiteMat interval predicate + compaction in one pass.
 
     params = int32[4] (plo, phi, olo, ohi); padding uses INT32_MAX which can
@@ -340,7 +271,8 @@ def interval_compact(p, o, params, cap: int, block: int = 512):
 
 
 @partial(jax.jit, static_argnames=("cap", "block"))
-def masked_interval_compact(p, o, alive, params, cap: int, block: int = 512):
+def masked_interval_compact(p, o, alive, params, cap: int,
+                            block: int = MIN_BLOCK):
     """Fused interval predicate + liveness mask + compaction in one pass.
 
     The live-store scan primitive: ``alive`` carries tombstones from the
@@ -359,9 +291,8 @@ def masked_interval_compact(p, o, alive, params, cap: int, block: int = 512):
 
 __all__ = [
     "interval_filter", "msc_select", "closure_expand", "pair_search",
-    "pair_search_windowed", "compact_indices", "dual_compact_indices",
+    "compact_indices", "dual_compact_indices",
     "interval_compact", "masked_interval_compact", "merge_gather",
-    "rewrite_member_compact",
     "two_source_gather", "segment_positions", "auto_block", "LARGE_BLOCK",
     "pass_counters", "reset_pass_counters", "ref",
 ]

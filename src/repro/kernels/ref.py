@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness contracts).
+"""Pure-jnp oracles for every kernel and device op (the correctness contracts).
 
 Each ``ref_*`` function defines the exact semantics its kernel must match;
 tests sweep shapes/dtypes and ``assert_allclose`` kernel-vs-ref (interpret
@@ -82,20 +82,13 @@ def ref_dual_compact(mask_a, mask_b, block: int):
     return la, ca, lb, cb
 
 
-def ref_pair_search(table_hi, table_lo, qhi, qlo):
-    """Left insertion point of each query pair in a lex-sorted pair table."""
-    from repro.utils import pair64
-
-    return pair64.searchsorted_pair(table_hi, table_lo, qhi, qlo, side="left")
-
-
 def ref_merge_sorted(a_hi, a_lo, b_hi, b_lo):
     """Gather map of the stable merge of two lex-sorted (hi, lo) runs.
 
     out[i] < n means merged slot i holds A[out[i]]; out[i] >= n means it
     holds B[out[i] - n].  Ties place A rows before B rows (the host
     ``index.merge_sorted`` contract: searchsorted side='right' for B) —
-    the semantics ``merge_path_pallas`` must match exactly.
+    the semantics ``ops.merge_gather`` must match exactly.
     """
     from repro.utils import pair64
 

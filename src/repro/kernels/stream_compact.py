@@ -1,46 +1,49 @@
-"""Pallas TPU kernel: stable stream compaction (count -> prefix-sum -> scatter).
+"""Pallas TPU kernel: stable stream compaction (count -> prefix-sum -> shift).
 
 The query engine's hot idiom was ``jnp.argsort(~mask, stable=True)[:cap]`` —
 an O(N log N) sort just to move matching rows to the front.  Compaction is
-the right primitive: each ``block``-sized tile counts its matches, computes
-per-match target slots with an intra-tile prefix sum, and scatters its
-*global row indices* to the front of its output tile (INVALID padding
-behind).  The host wrapper (kernels/ops.py) stitches tiles together with one
-exclusive prefix sum over the per-tile counts plus a single gather — O(N)
-total, and the per-tile counts double as the match count, so the engine no
-longer needs a separate counting pass over the store.
+the right primitive: each ``block``-sized tile moves the *global row
+indices* of its matches to the front of its output tile (INVALID padding
+behind) and reports its match count.  The host wrapper (kernels/ops.py)
+stitches tiles together with one exclusive prefix sum over the per-tile
+counts plus a single gather — O(N) total, and the per-tile counts double as
+the match count, so the engine needs no separate counting pass.
 
-The intra-tile scatter is a CHUNKED cumsum + dynamic-slice store: the tile
-is cut into ``chunk``-sized pieces (default 256); each piece resolves its
-matches with a (chunk, chunk) one-hot select-and-reduce (TPU has no vector
-scatter, so the smallest compare cube that fits the VPU is the scatter),
-and the piece's compacted run is stored at the tile-local running offset
-with one ``pl.ds`` dynamic-slice write.  VMEM for the cube is O(chunk^2)
-*independent of block*, so blocks grow to 4096+ (the old formulation was a
-(block, block) cube — 64 MB at block=4096 — which capped blocks at 512);
-larger blocks mean 8x fewer grid steps and tile-count segments per store
-pass, the difference between "toy" and multi-million-row scans.
+A tile is a ``(block // 128, 128)`` int32 slab: rows on sublanes, 128
+lanes, so ``block`` is a multiple of one (8, 128) vreg tile (1024 rows).
+The body uses only element-wise ops, two small matmuls and ``pltpu.roll``
+— Mosaic has no vector gather, scatter, cumsum or unaligned dynamic
+store, and needs none of them here:
+
+  1. a prefix count of the non-matches gives every match its
+     displacement ``d``: an upper-triangular 0/1 matmul scans the lanes of
+     each row, a strictly-lower-triangular one adds the rows before it
+     (f32 on 0/1 and <= 128 inputs with f32 accumulation: exact);
+  2. a log-step shift network moves each match left by ``d``: step ``s``
+     (1, 2, 4, ...) shifts the matches whose ``d`` has bit ``s`` set by
+     ``s`` slots.  Taking the bits low to high never lands two matches on
+     one slot (their gap always exceeds the difference of their partial
+     shifts), so each step is one roll plus two selects.  The steps run
+     as a ``fori_loop`` with the shift as a traced value, which keeps the
+     program small for the interpreter and the CPU compiler.
+
+Matches travel packed as ``d << 16 | local index`` (-1 marks an empty
+slot), which caps ``block`` at 2**15.
 
 Four entry points share the body:
 
   * ``stream_compact_pallas``   — compacts an arbitrary precomputed mask
     (spill intervals, member sets, rewrite-mode type masks),
   * ``interval_compact_pallas`` — fuses the LiteMat interval predicate
-    (kernels/interval_filter.py) with compaction in ONE pass over the
-    store: p in [plo, phi) AND o in [olo, ohi), constants in SMEM,
+    ``p in [plo, phi) AND o in [olo, ohi)`` (constants in SMEM) with
+    compaction in ONE pass over the store,
   * ``masked_interval_compact_pallas`` — the live-store variant: the same
-    fused predicate ANDed with a per-row liveness (tombstone) mask, so a
-    delta-overlaid scan (core/delta.py) filters deleted rows in the same
-    single pass instead of compacting twice,
+    predicate ANDed with a per-row liveness (tombstone) mask,
   * ``dual_compact_pallas``     — TWO masks over the same rows compacted
-    into two independent output streams in one grid pass.  The rewrite-mode
-    dual-branch type pattern (dom∩rng predicates bind BOTH endpoints,
-    core/query.py) needs a subject-binding and an object-binding compaction
-    over the same store; emitting both per tile halves its kernel passes.
+    into two independent output streams in one grid pass (the rewrite-mode
+    type pattern's subject- and object-binding branches).
 """
 from __future__ import annotations
-
-from functools import partial
 
 import numpy as np
 
@@ -50,254 +53,178 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK = 512
-DEFAULT_CHUNK = 256
+LANES = 128
+MIN_BLOCK = 8 * LANES  # one (8, 128) int32 vreg tile
+MAX_BLOCK = 1 << 15  # local index and displacement share one int32
+DEFAULT_BLOCK = 4096
 INVALID = np.int32(np.iinfo(np.int32).max)
 
 
-def _chunk_of(block: int, chunk: int) -> int:
-    """Effective chunk: never larger than the tile, must divide it."""
-    c = min(chunk, block)
-    if block % c:
-        raise ValueError(f"chunk {c} must divide block {block}")
-    return c
+def _check_block(n: int, block: int) -> None:
+    if block % MIN_BLOCK or not MIN_BLOCK <= block <= MAX_BLOCK:
+        raise ValueError(f"block {block} must be a multiple of {MIN_BLOCK} "
+                         f"in [{MIN_BLOCK}, {MAX_BLOCK}]")
+    if n % block:
+        raise ValueError(f"length {n} is not a multiple of block {block}")
 
 
-def _compact_body(m, idx_ref, cnt_ref, chunk: int):
-    """m: int32[block] 0/1 -> front-compacted global indices + tile count.
-
-    Chunked: each ``chunk`` of the tile resolves its own matches with a
-    (chunk, chunk) one-hot reduce, then lands at the tile-local running
-    offset (the exclusive cumsum of chunk counts, carried through the loop)
-    with one dynamic-slice store.  A chunk's local run is INVALID past its
-    own count, and chunk c's store begins exactly where chunk c-1's matches
-    end, so every stale INVALID tail is overwritten by the next chunk's
-    run and the final tail stays INVALID — the tile's output is the tile's
-    matches in ascending order, INVALID-padded, same contract as before.
-    """
-    block = m.shape[0]
-    chunk = _chunk_of(block, chunk)
-    n_chunks = block // chunk
-    base = pl.program_id(0) * block
-    if n_chunks == 1:
-        vals, cnt = _chunk_compact(m, base)
-        idx_ref[...] = vals
-        cnt_ref[0] = cnt
-        return
-    idx_ref[...] = jnp.full((block,), INVALID, jnp.int32)
-
-    def body(c, off):
-        mc = lax.dynamic_slice(m, (c * chunk,), (chunk,))
-        vals, cnt = _chunk_compact(mc, base + c * chunk)
-        idx_ref[pl.ds(off, chunk)] = vals
-        return off + cnt
-
-    cnt_ref[0] = lax.fori_loop(0, n_chunks, body, jnp.int32(0))
+def _roll_flat(x, k):
+    """``jnp.roll`` by traced ``k`` in [0, size) over the row-major
+    flattening of a (rows, 128) tile: ``y[p] = x[(p - k) mod size]``, built
+    from one lane roll and two sublane rolls."""
+    rows = x.shape[0]
+    q, r = k // LANES, k % LANES
+    a = pltpu.roll(x, r, 1)
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane >= r, pltpu.roll(a, q % rows, 0),
+                     pltpu.roll(a, (q + 1) % rows, 0))
 
 
-def _chunk_compact(m, gbase):
-    """int32[chunk] 0/1 -> (compacted global indices, INVALID-padded; count)."""
-    chunk = m.shape[0]
-    m2 = m.reshape(1, chunk)
-    pos = jnp.cumsum(m2, axis=1) - 1  # target slot of each match
-    cnt = jnp.sum(m2)
-    out_slot = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    src_idx = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    sel = (pos == out_slot) & (m2 != 0)  # one-hot: slot j <- source i
-    local = jnp.sum(jnp.where(sel, src_idx, 0), axis=1)  # int32[chunk]
-    slot = lax.broadcasted_iota(jnp.int32, (1, chunk), 1).reshape(chunk)
-    return jnp.where(slot < cnt, local + gbase, INVALID), cnt
+def _iotas(n: int):
+    return (lax.broadcasted_iota(jnp.int32, (n, n), 0),
+            lax.broadcasted_iota(jnp.int32, (n, n), 1))
 
 
-def _mask_kernel(mask_ref, idx_ref, cnt_ref, *, chunk):
-    _compact_body(mask_ref[...].astype(jnp.int32), idx_ref, cnt_ref, chunk)
+def _prefix_count(z):
+    """Inclusive prefix sum of a 0/1 (rows, 128) tile over its row-major
+    flattening, as two 0/1 matmuls."""
+    zf = z.astype(jnp.float32)
+    i, j = _iotas(LANES)
+    lanes = jnp.dot(zf, (i <= j).astype(jnp.float32),
+                    preferred_element_type=jnp.float32)
+    row_tot = jnp.broadcast_to(jnp.sum(zf, axis=1, keepdims=True), zf.shape)
+    i, j = _iotas(z.shape[0])
+    rows_before = jnp.dot((j < i).astype(jnp.float32), row_tot,
+                          preferred_element_type=jnp.float32)
+    return (lanes + rows_before).astype(jnp.int32)
 
 
-def _fused_kernel(params_ref, p_ref, o_ref, idx_ref, cnt_ref, *, chunk):
+def _compact_tile(m, base):
+    """m: int32 (rows, 128) 0/1 tile -> (front-compacted global indices,
+    INVALID-padded; tile match count)."""
+    size = m.shape[0] * LANES
+    flat = (lax.broadcasted_iota(jnp.int32, m.shape, 0) * LANES
+            + lax.broadcasted_iota(jnp.int32, m.shape, 1))
+    z = 1 - m
+    d = _prefix_count(z) - z  # non-matches strictly before each slot
+    x = jnp.where(m != 0, (d << 16) | flat, -1)
+
+    def step(k, x):
+        s = jnp.left_shift(jnp.int32(1), k)
+        # y[p] = x[p + s]; slots past the tile end wrap to its head, where
+        # no match can still owe a shift of s, so they never arrive
+        up = _roll_flat(x, size - s)
+        arrive = (up >= 0) & (((up >> 16) & s) != 0)
+        leave = (x >= 0) & (((x >> 16) & s) != 0)
+        return jnp.where(arrive, up, jnp.where(leave, -1, x))
+
+    x = lax.fori_loop(0, size.bit_length() - 1, step, x)
+    return jnp.where(x >= 0, (x & 0xFFFF) + base, INVALID), jnp.sum(m)
+
+
+def _emit(m, idx_ref, cnt_ref):
+    base = pl.program_id(0) * (idx_ref.shape[0] * LANES)
+    vals, cnt = _compact_tile(m, base)
+    idx_ref[...] = vals
+    cnt_ref[...] = jnp.full(cnt_ref.shape, cnt, jnp.int32)
+
+
+def _mask_kernel(mask_ref, idx_ref, cnt_ref):
+    _emit(mask_ref[...], idx_ref, cnt_ref)
+
+
+def _interval_mask(params_ref, p_ref, o_ref):
     plo, phi = params_ref[0], params_ref[1]
     olo, ohi = params_ref[2], params_ref[3]
     p = p_ref[...]
     o = o_ref[...]
-    m = (p >= plo) & (p < phi) & (o >= olo) & (o < ohi)
-    _compact_body(m.astype(jnp.int32), idx_ref, cnt_ref, chunk)
+    return (p >= plo) & (p < phi) & (o >= olo) & (o < ohi)
+
+
+def _fused_kernel(params_ref, p_ref, o_ref, idx_ref, cnt_ref):
+    m = _interval_mask(params_ref, p_ref, o_ref)
+    _emit(m.astype(jnp.int32), idx_ref, cnt_ref)
 
 
 def _masked_fused_kernel(params_ref, p_ref, o_ref, alive_ref, idx_ref,
-                         cnt_ref, *, chunk):
-    plo, phi = params_ref[0], params_ref[1]
-    olo, ohi = params_ref[2], params_ref[3]
-    p = p_ref[...]
-    o = o_ref[...]
-    m = (p >= plo) & (p < phi) & (o >= olo) & (o < ohi) & (alive_ref[...] != 0)
-    _compact_body(m.astype(jnp.int32), idx_ref, cnt_ref, chunk)
+                         cnt_ref):
+    m = _interval_mask(params_ref, p_ref, o_ref) & (alive_ref[...] != 0)
+    _emit(m.astype(jnp.int32), idx_ref, cnt_ref)
 
 
-def _dual_kernel(ma_ref, mb_ref, idxa_ref, cnta_ref, idxb_ref, cntb_ref,
-                 *, chunk):
-    _compact_body(ma_ref[...].astype(jnp.int32), idxa_ref, cnta_ref, chunk)
-    _compact_body(mb_ref[...].astype(jnp.int32), idxb_ref, cntb_ref, chunk)
+def _dual_kernel(ma_ref, mb_ref, idxa_ref, cnta_ref, idxb_ref, cntb_ref):
+    _emit(ma_ref[...], idxa_ref, cnta_ref)
+    _emit(mb_ref[...], idxb_ref, cntb_ref)
 
 
-def _in_set_tile(col, arr):
-    """Vectorized sorted-membership test inside a kernel tile.
+def _compact_call(kernel, n: int, block: int, n_smem: int, n_tiled: int,
+                  streams: int, interpret: bool):
+    """pallas_call over ``n // block`` tiles of (block // 128, 128) rows.
 
-    ``arr`` is a lex-sorted INT32_MAX-padded pow2-length id set resident
-    on-chip for the whole grid pass.  log2(K) binary-search steps with
-    vector gathers (the merge-path kernels' ref-gather idiom) stand in
-    for ``jnp.searchsorted``, which does not lower inside Pallas bodies.
+    Each stream emits its compacted indices tile-for-tile and its count
+    broadcast over one (1, 1, 128) block — a rank-1 (1,) block per tile is
+    not a TPU tiling.
     """
-    K = arr.shape[0]
-    lo = jnp.zeros(col.shape, jnp.int32)
-    hi = jnp.full(col.shape, K, jnp.int32)
-
-    def step(_, lh):
-        l, h = lh
-        mid = (l + h) // 2
-        v = arr[jnp.clip(mid, 0, K - 1)]
-        right = v < col
-        return jnp.where(right, mid + 1, l), jnp.where(right, h, mid)
-
-    lo, hi = lax.fori_loop(0, max(int(K).bit_length(), 1), step, (lo, hi))
-    pos = jnp.clip(lo, 0, K - 1)
-    return (arr[pos] == col) & (col != INVALID)
-
-
-def _member_kernel(params_ref, mem_ref, dom_ref, rng_ref, s_ref, p_ref,
-                   o_ref, alive_ref, *out_refs, chunk, has_dom, has_rng):
-    """Rewrite-mode type-pattern masks fused with compaction.
-
-    Computes the subject-binding mask ``(p == tid & o ∈ mem) [| p ∈ dom]``
-    and (statically gated) the object-binding mask ``p ∈ rng`` per tile —
-    the member/domain/range id sets stay on-chip across the whole grid
-    pass, so the full-store boolean masks the host path materialized
-    never exist: each tile resolves its own membership tests and compacts
-    in place.  ``tid`` rides in SMEM; absent branches compile to nothing.
-    """
-    tid = params_ref[0]
-    s = s_ref[...]
-    p = p_ref[...]
-    o = o_ref[...]
-    valid = (s != INVALID) & (alive_ref[...] != 0)
-    m_s = (p == tid) & _in_set_tile(o, mem_ref[...])
-    if has_dom:
-        m_s = m_s | _in_set_tile(p, dom_ref[...])
-    _compact_body((m_s & valid).astype(jnp.int32), out_refs[0], out_refs[1],
-                  chunk)
-    if has_rng:
-        m_o = _in_set_tile(p, rng_ref[...]) & valid
-        _compact_body(m_o.astype(jnp.int32), out_refs[2], out_refs[3], chunk)
+    _check_block(n, block)
+    nb, rows = n // block, block // LANES
+    tile = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
+    count = pl.BlockSpec((1, 1, LANES), lambda i: (i, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(nb,),
+        in_specs=([pl.BlockSpec(memory_space=pltpu.SMEM)] * n_smem
+                  + [tile] * n_tiled),
+        out_specs=[tile, count] * streams,
+        out_shape=[jax.ShapeDtypeStruct((n // LANES, LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((nb, 1, LANES), jnp.int32)] * streams,
+        interpret=interpret,
+    )
 
 
-def _compact_specs(block: int, nb: int, n: int, streams: int = 1):
-    out_specs, out_shape = [], []
-    for _ in range(streams):
-        out_specs += [pl.BlockSpec((block,), lambda i: (i,)),
-                      pl.BlockSpec((1,), lambda i: (i,))]
-        out_shape += [jax.ShapeDtypeStruct((n,), jnp.int32),
-                      jax.ShapeDtypeStruct((nb,), jnp.int32)]
-    return out_specs, out_shape
+def _tiles(x):
+    return x.reshape(-1, LANES)
+
+
+def _streams(outs):
+    """Kernel outputs -> flat (indices int32[N], counts int32[N/block]) per
+    stream, in order."""
+    res = []
+    for i in range(0, len(outs), 2):
+        res += [outs[i].reshape(-1), outs[i + 1][:, 0, 0]]
+    return tuple(res)
 
 
 def stream_compact_pallas(mask, *, block: int = DEFAULT_BLOCK,
-                          chunk: int = DEFAULT_CHUNK, interpret: bool = False):
-    """mask: int32[N] (N a multiple of block) ->
+                          interpret: bool = False):
+    """mask: int32[N] 0/1 (N a multiple of block) ->
     (tile-compacted global indices int32[N], per-tile counts int32[N/block])."""
     n = mask.shape[0]
-    nb = n // block
-    out_specs, out_shape = _compact_specs(block, nb, n)
-    return pl.pallas_call(
-        partial(_mask_kernel, chunk=chunk),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(mask)
+    call = _compact_call(_mask_kernel, n, block, 0, 1, 1, interpret)
+    return _streams(call(_tiles(mask)))
 
 
 def interval_compact_pallas(p, o, params, *, block: int = DEFAULT_BLOCK,
-                            chunk: int = DEFAULT_CHUNK,
                             interpret: bool = False):
     """p, o: int32[N]; params: int32[4] = (plo, phi, olo, ohi) ->
     (tile-compacted match indices, per-tile counts) — predicate fused."""
     n = p.shape[0]
-    nb = n // block
-    out_specs, out_shape = _compact_specs(block, nb, n)
-    return pl.pallas_call(
-        partial(_fused_kernel, chunk=chunk),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(params, p, o)
+    call = _compact_call(_fused_kernel, n, block, 1, 2, 1, interpret)
+    return _streams(call(params, _tiles(p), _tiles(o)))
 
 
 def masked_interval_compact_pallas(p, o, alive, params, *,
                                    block: int = DEFAULT_BLOCK,
-                                   chunk: int = DEFAULT_CHUNK,
                                    interpret: bool = False):
     """p, o, alive: int32[N]; params: int32[4] = (plo, phi, olo, ohi) ->
     (tile-compacted match indices, per-tile counts) — interval predicate and
     tombstone filter fused in one pass."""
     n = p.shape[0]
-    nb = n // block
-    out_specs, out_shape = _compact_specs(block, nb, n)
-    return pl.pallas_call(
-        partial(_masked_fused_kernel, chunk=chunk),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(params, p, o, alive)
-
-
-def member_compact_pallas(params, mem, dom, rng, s, p, o, alive, *,
-                          has_dom: bool, has_rng: bool,
-                          block: int = DEFAULT_BLOCK,
-                          chunk: int = DEFAULT_CHUNK,
-                          interpret: bool = False):
-    """Fused rewrite-mode type-pattern predicate + compaction.
-
-    ``params`` = int32[1] (tid) in SMEM; ``mem``/``dom``/``rng`` are
-    lex-sorted INT32_MAX-padded id sets resident on-chip (constant index
-    maps — one DMA for the whole grid); ``s``/``p``/``o``/``alive`` tile.
-    Emits the subject-binding stream, plus the object-binding stream when
-    ``has_rng`` — each satisfying the ``stream_compact_pallas`` contract.
-    """
-    n = s.shape[0]
-    nb = n // block
-    streams = 2 if has_rng else 1
-    out_specs, out_shape = _compact_specs(block, nb, n, streams)
-    resident = [pl.BlockSpec((a.shape[0],), lambda i: (0,))
-                for a in (mem, dom, rng)]
-    return pl.pallas_call(
-        partial(_member_kernel, chunk=chunk, has_dom=has_dom,
-                has_rng=has_rng),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), *resident,
-                  pl.BlockSpec((block,), lambda i: (i,)),
-                  pl.BlockSpec((block,), lambda i: (i,)),
-                  pl.BlockSpec((block,), lambda i: (i,)),
-                  pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(params, mem, dom, rng, s, p, o, alive)
+    call = _compact_call(_masked_fused_kernel, n, block, 1, 3, 1, interpret)
+    return _streams(call(params, _tiles(p), _tiles(o), _tiles(alive)))
 
 
 def dual_compact_pallas(mask_a, mask_b, *, block: int = DEFAULT_BLOCK,
-                        chunk: int = DEFAULT_CHUNK, interpret: bool = False):
+                        interpret: bool = False):
     """Two int32[N] masks -> two (indices, per-tile counts) streams, one pass.
 
     Each stream independently satisfies the ``stream_compact_pallas``
@@ -305,14 +232,5 @@ def dual_compact_pallas(mask_a, mask_b, *, block: int = DEFAULT_BLOCK,
     so the dual-branch consumer pays one grid pass instead of two.
     """
     n = mask_a.shape[0]
-    nb = n // block
-    out_specs, out_shape = _compact_specs(block, nb, n, streams=2)
-    return pl.pallas_call(
-        partial(_dual_kernel, chunk=chunk),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                  pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(mask_a, mask_b)
+    call = _compact_call(_dual_kernel, n, block, 0, 2, 2, interpret)
+    return _streams(call(_tiles(mask_a), _tiles(mask_b)))

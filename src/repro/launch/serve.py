@@ -22,6 +22,7 @@ from repro.core.engine import PAPER_QUERIES, KnowledgeBase
 from repro.rdf.generator import generate_lubm
 from repro.serving.engine import QueryServer
 from repro.serving.runtime import ServingRuntime
+from repro.utils.compile_cache import enable_compile_cache
 
 CLASSES = ["Professor", "Student", "Faculty", "Person", "Course",
            "Publication", "Organization", "Department", "Chair",
@@ -76,6 +77,7 @@ def main():
     ap.add_argument("--max-queue", type=int, default=64)
     ap.add_argument("--deadline-s", type=float, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print(f"generating LUBM-like KB ({args.universities} universities)...")
     raw = generate_lubm(args.universities, seed=args.seed)

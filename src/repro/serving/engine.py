@@ -42,20 +42,19 @@ import jax
 import jax.numpy as jnp
 from functools import partial
 
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.core.engine import KnowledgeBase
 from repro.core.index import TypeIndex
 from repro.kernels import ops
 from repro.obs.metrics import REGISTRY
-from repro.utils.jaxcompat import make_mesh, shard_map
 
 INVALID = jnp.int32(np.iinfo(np.int32).max)
 
 
 def _distinct_count_topk(hits, topk: int):
     """Sorted-dedup count + first-k distinct values of INVALID-padded hits."""
-    h = jnp.sort(hits)
+    h = jnp.sort(hits, stable=False)
     first = jnp.concatenate([jnp.ones((1,), bool), h[1:] != h[:-1]])
     uniq = first & (h != INVALID)
     count = uniq.astype(jnp.int32).sum()
@@ -271,7 +270,7 @@ def _merge_members(members, topk: int):
     S, B, _ = members.shape
     m = jnp.where(members < 0, INVALID, members)
     m = jnp.transpose(m, (1, 0, 2)).reshape(B, -1)
-    m = jnp.sort(m, axis=1)[:, :topk]
+    m = jnp.sort(m, axis=1, stable=False)[:, :topk]
     return jnp.where(m == INVALID, -1, m)
 
 
@@ -395,13 +394,14 @@ class ShardedQueryServer:
         fn = self._fans.get(key)
         if fn is None:
             if self._sm():
-                mesh = make_mesh((self.K.n_shards,), ("shard",))
+                mesh = jax.make_mesh((self.K.n_shards,), ("shard",),
+                                     axis_types=(AxisType.Auto,))
 
                 def body(su, st, ln):
                     c, m = _members_shard(su[0], st[0], ln[0], cap, self.topk)
                     return c[None], m[None]
 
-                fn = jax.jit(shard_map(
+                fn = jax.jit(jax.shard_map(
                     body, mesh=mesh, in_specs=(P("shard"),) * 3,
                     out_specs=(P("shard"),) * 2, check_vma=False))
             else:
@@ -417,7 +417,8 @@ class ShardedQueryServer:
         fn = self._fans.get(key)
         if fn is None:
             if self._sm():
-                mesh = make_mesh((self.K.n_shards,), ("shard",))
+                mesh = jax.make_mesh((self.K.n_shards,), ("shard",),
+                                     axis_types=(AxisType.Auto,))
 
                 def body(su, s_, p_, st, ln, lo, hi):
                     c, m = _prop_join_shard(
@@ -425,7 +426,7 @@ class ShardedQueryServer:
                         cap, self.topk, kp)
                     return c[None], m[None]
 
-                fn = jax.jit(shard_map(
+                fn = jax.jit(jax.shard_map(
                     body, mesh=mesh, in_specs=(P("shard"),) * 7,
                     out_specs=(P("shard"),) * 2, check_vma=False))
             else:

@@ -5,8 +5,8 @@ dictionary operation works on two parallel int32 planes holding the top/bottom
 31 bits of a 62-bit fingerprint.  Lexicographic (hi, lo) order equals numeric
 order of the original value, so sort / unique / binary-search all transfer.
 
-The vectorized binary search below is also implemented as a Pallas kernel
-(kernels/pair_search.py); this module is the jnp oracle.
+The vectorized binary search below is the device search every backend runs
+(``kernels/ops.pair_search`` wraps it).
 """
 from __future__ import annotations
 
@@ -56,7 +56,7 @@ def searchsorted_pair(table_hi, table_lo, qhi, qlo, side: str = "left"):
     """Vectorized binary search over a lex-sorted pair table.
 
     Returns, per query, the insertion index (side='left') — ~34 gather steps
-    regardless of query count; maps 1:1 onto the Pallas kernel.
+    regardless of query count.
     """
     import jax.lax as lax
 

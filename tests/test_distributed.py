@@ -1,40 +1,28 @@
 """Multi-device semantics via subprocesses (this process keeps 1 device;
 XLA locks the device count at first jax init, so each test spawns a child
 with XLA_FLAGS=--xla_force_host_platform_device_count=8)."""
+import os
 import subprocess
 import sys
+from pathlib import Path
 
-import pytest
-
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _sm  # noqa: F401
-    _NEW_JAX = True
-except ImportError:
-    _NEW_JAX = False
-
-# On jax 0.4.x the repro.utils.jaxcompat shim makes these programs *run*,
-# but the check_rep-era shard_map on forced-multi-device CPU is orders of
-# magnitude slower — minutes per subprocess — so they are excluded from
-# tier-1 there rather than blowing the suite budget.
-pytestmark = pytest.mark.skipif(
-    not _NEW_JAX,
-    reason="multi-device subprocess tests need jax>=0.6 (0.4.x compat path "
-           "is functional but too slow for tier-1)")
-
-REPO = "src"
+REPO = Path(__file__).resolve().parents[1]
 
 
 def _run(code: str, devices: int = 8):
+    """Run ``code`` in a child on ``devices`` forced CPU devices; the child
+    never claims an accelerator, so it cannot contend with its parent."""
     r = subprocess.run(
         [sys.executable, "-c", code],
         capture_output=True, text=True, timeout=560,
         env={
-            "PYTHONPATH": REPO,
+            "PYTHONPATH": str(REPO / "src"),
+            "JAX_PLATFORMS": "cpu",
             "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
             "PATH": "/usr/bin:/bin",
-            "HOME": "/root",
+            "HOME": os.environ.get("HOME", str(REPO)),
         },
-        cwd="/root/repo",
+        cwd=REPO,
     )
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-3000:]}"
     return r.stdout
@@ -44,8 +32,7 @@ def test_sharded_dictionary_matches_local():
     out = _run(
         """
 import numpy as np, jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro.utils.jaxcompat import make_mesh, shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.core import dictionary as dct
 from repro.utils import pair64
 
@@ -54,10 +41,10 @@ n_shards, per = 8, 64
 fps = rng.choice(1 << 50, n_shards * per // 2, replace=False)
 occ = rng.choice(fps, n_shards * per)  # duplicated occurrences
 hi, lo = pair64.split_np(occ)
-mesh = make_mesh((n_shards,), ('d',))
+mesh = jax.make_mesh((n_shards,), ('d',), axis_types=(AxisType.Auto,))
 body = dct.sharded_dictionary_fn('d', n_shards, bin_cap=per, base=1000)
-f = shard_map(body, mesh=mesh, in_specs=(P('d'), P('d'), P('d')),
-              out_specs=dct.sharded_out_specs(), check_vma=False)
+f = jax.shard_map(body, mesh=mesh, in_specs=(P('d'), P('d'), P('d')),
+                  out_specs=dct.sharded_out_specs(), check_vma=False)
 ids, table, overflow, counts = f(jnp.asarray(hi), jnp.asarray(lo),
                                  jnp.ones(occ.shape, bool))
 ids = np.asarray(ids)
@@ -133,8 +120,9 @@ def test_mini_dryrun_lm_cell():
 import jax
 from repro.launch.cells import build_cell
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.utils.jaxcompat import make_mesh
-mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'),
+                     axis_types=(AxisType.Auto,) * 3)
 cell = build_cell('olmoe-1b-7b', 'train_4k', mesh)
 jfn = jax.jit(cell.fn, in_shardings=cell.shardings(mesh))
 compiled = jfn.lower(*cell.abstract_args).compile()

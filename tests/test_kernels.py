@@ -1,7 +1,9 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps + property tests.
+"""Pallas kernels and device ops vs pure-jnp oracles: shape/dtype sweeps +
+property tests.
 
 Kernels run in interpret mode on CPU (the kernel bodies execute verbatim);
-on a real TPU the same wrappers compile the Mosaic path.
+on a real TPU the same wrappers compile the Mosaic path
+(tests/test_tpu_compile.py compiles them for a v5e without one).
 """
 import numpy as np
 import pytest
@@ -55,34 +57,27 @@ def test_closure_expand_sweep(C, D, n, rng):
 
 
 @pytest.mark.parametrize("T,N", [(0, 5), (300, 7), (2048, 2048), (5000, 1300)])
-@pytest.mark.parametrize("block", [256, 512])
-def test_pair_search_windowed_matches_resident(T, N, block, rng):
-    """The merge-path-partitioned reuse must equal the resident kernel and
-    the numpy searchsorted oracle bit-exactly ('left' contract), at any
-    table/query size — including tables past the resident VMEM dispatch."""
-    hi = np.sort(rng.integers(0, 50, T).astype(np.int32))
+@pytest.mark.parametrize("hi_space", [3, 50])  # duplicate-density sweep
+def test_pair_search_windowed_matches_resident(T, N, hi_space, rng):
+    """The pair search must equal the numpy searchsorted oracle bit-exactly
+    ('left' contract) at any table/query size, empty tables included."""
+    hi = np.sort(rng.integers(0, hi_space, T).astype(np.int32))
     lo = rng.integers(0, 1000, T).astype(np.int32)
     order = np.lexsort((lo, hi))
     hi, lo = hi[order], lo[order]
-    qh = rng.integers(0, 52, N).astype(np.int32)
+    qh = rng.integers(0, hi_space + 2, N).astype(np.int32)
     ql = rng.integers(-5, 1005, N).astype(np.int32)
     off = np.int64(np.iinfo(np.int32).min)
     key = hi.astype(np.int64) * (1 << 32) + (lo.astype(np.int64) - off)
     qkey = qh.astype(np.int64) * (1 << 32) + (ql.astype(np.int64) - off)
     want = np.searchsorted(key, qkey, side="left")
-    got = np.asarray(ops.pair_search_windowed(
-        jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(qh), jnp.asarray(ql),
-        block=block))
+    got = np.asarray(ops.pair_search(
+        jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(qh), jnp.asarray(ql)))
     np.testing.assert_array_equal(got, want)
-    if T:
-        res = np.asarray(ops.pair_search(
-            jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(qh),
-            jnp.asarray(ql)))
-        np.testing.assert_array_equal(res, want)
 
 
 @pytest.mark.parametrize("n", [1, 100, 512, 1000, 5000])
-@pytest.mark.parametrize("block", [256, 512])
+@pytest.mark.parametrize("block", [1024, 2048])
 @pytest.mark.parametrize("density", [0.0, 0.3, 1.0])
 def test_stream_compact_sweep(n, block, density, rng):
     from repro.kernels.stream_compact import stream_compact_pallas
@@ -130,33 +125,45 @@ def test_masked_interval_compact_fused(n, density, rng):
     np.testing.assert_array_equal(np.asarray(take)[np.asarray(ok)], want[:256])
 
 
-@pytest.mark.parametrize("block", [512, 1024, 4096])
-@pytest.mark.parametrize("chunk", [128, 256, 512])
-@pytest.mark.parametrize("density", [0.0, 0.13, 1.0])
-def test_stream_compact_chunked_sweep(block, chunk, density, rng):
-    """Chunked-cumsum body == ref across block x chunk x density.
+def _mask_pattern(rng, n, density, pattern):
+    """0/1 int32 mask: iid bits, long runs, or bits clustered at one end."""
+    if pattern == "runs":  # runs of 1..300 equal bits
+        bits, val = [], rng.random() < density
+        while len(bits) < n:
+            bits += [val] * int(rng.integers(1, 300))
+            val = rng.random() < density
+        return np.asarray(bits[:n], np.int32)
+    m = rng.random(n) < density
+    if pattern == "tail":  # every match in the highest slots
+        m = np.sort(m)
+    return m.astype(np.int32)
 
-    The chunked rewrite must be bit-identical for every chunking of the
-    tile — including blocks past the old 512 one-hot ceiling — and for the
-    empty-output (density 0) and all-survivors (density 1) edges, where
-    the dynamic-slice stores degenerate to nothing / the whole tile.
+
+@pytest.mark.parametrize("block", [1024, 2048, 4096])
+@pytest.mark.parametrize("pattern", ["iid", "runs", "tail"])
+@pytest.mark.parametrize("density", [0.0, 0.13, 1.0])
+def test_stream_compact_chunked_sweep(block, pattern, density, rng):
+    """Shift-network body == ref across block x mask shape x density.
+
+    Long runs and tail-clustered matches drive the largest displacements
+    (every shift step fires); density 0 and 1 are the empty-output and
+    all-survivors edges, where no match moves or every slot fills.
     """
     from repro.kernels.stream_compact import stream_compact_pallas
 
     n = block * 2 + block // 2  # partial final tile after padding
-    mask = jnp.asarray(rng.random(n) < density)
-    padded = ops._pad1(mask.astype(jnp.int32), block, np.int32(0))
-    loc, cnt = stream_compact_pallas(padded, block=block, chunk=chunk,
-                                     interpret=True)
+    mask = jnp.asarray(_mask_pattern(rng, n, density, pattern))
+    padded = ops._pad1(mask, block, np.int32(0))
+    loc, cnt = stream_compact_pallas(padded, block=block, interpret=True)
     rloc, rcnt = ref.ref_stream_compact(padded, block)
     np.testing.assert_array_equal(np.asarray(loc), np.asarray(rloc))
     np.testing.assert_array_equal(np.asarray(cnt), np.asarray(rcnt))
 
 
-@pytest.mark.parametrize("block", [512, 4096])
+@pytest.mark.parametrize("block", [1024, 4096])
 @pytest.mark.parametrize("n", [100, 5000, 9000])
 def test_compact_indices_large_blocks(block, n, rng):
-    """The assembled wrapper is block-size invariant (4096 == 512 == ref)."""
+    """The assembled wrapper is block-size invariant (4096 == 1024 == ref)."""
     mask = jnp.asarray(rng.random(n) < 0.2)
     want = np.flatnonzero(np.asarray(mask))
     for cap in (8, 1 << 13):
@@ -166,7 +173,7 @@ def test_compact_indices_large_blocks(block, n, rng):
                                       want[:cap])
 
 
-@pytest.mark.parametrize("block", [512, 4096])
+@pytest.mark.parametrize("block", [1024, 4096])
 @pytest.mark.parametrize("n", [513, 5000])
 @pytest.mark.parametrize("density", [0.0, 0.4, 1.0])
 def test_masked_interval_compact_block_sweep(n, block, density, rng):
@@ -185,7 +192,7 @@ def test_masked_interval_compact_block_sweep(n, block, density, rng):
                                   want[:256])
 
 
-@pytest.mark.parametrize("block", [512, 1024, 4096])
+@pytest.mark.parametrize("block", [1024, 2048, 4096])
 @pytest.mark.parametrize("da,db", [(0.0, 0.0), (0.2, 0.9), (1.0, 1.0),
                                    (0.0, 1.0)])
 def test_dual_compact_sweep(block, da, db, rng):
@@ -222,19 +229,30 @@ def test_dual_compact_indices_wrapper(rng):
 
 
 @given(st.integers(1, 6000), st.integers(0, 2**31 - 2),
-       st.sampled_from([512, 1024, 4096]), st.sampled_from([128, 256]))
+       st.sampled_from([1024, 2048, 4096]),
+       st.sampled_from(["iid", "runs", "tail"]))
 @settings(max_examples=20, deadline=None)
-def test_stream_compact_chunked_property(n, seed, block, chunk):
+def test_stream_compact_chunked_property(n, seed, block, pattern):
     from repro.kernels.stream_compact import stream_compact_pallas
 
     rng = np.random.default_rng(seed)
-    mask = jnp.asarray((rng.random(n) < rng.random()).astype(np.int32))
+    mask = jnp.asarray(_mask_pattern(rng, n, rng.random(), pattern))
     padded = ops._pad1(mask, block, np.int32(0))
-    loc, cnt = stream_compact_pallas(padded, block=block, chunk=chunk,
-                                     interpret=True)
+    loc, cnt = stream_compact_pallas(padded, block=block, interpret=True)
     rloc, rcnt = ref.ref_stream_compact(padded, block)
     np.testing.assert_array_equal(np.asarray(loc), np.asarray(rloc))
     np.testing.assert_array_equal(np.asarray(cnt), np.asarray(rcnt))
+
+
+@pytest.mark.parametrize("block", [512, 1536, 1 << 16])
+def test_stream_compact_rejects_untiled_block(block):
+    """Tiles are whole (8, 128) int32 vreg tiles, at most 2**15 rows: any
+    other block is refused on every backend, not only by the TPU compiler."""
+    from repro.kernels.stream_compact import stream_compact_pallas
+
+    with pytest.raises(ValueError, match="block"):
+        stream_compact_pallas(jnp.zeros((1 << 16,), jnp.int32), block=block,
+                              interpret=True)
 
 
 def _sorted_pair_run(rng, n, key_space):
@@ -249,7 +267,7 @@ def _sorted_pair_run(rng, n, key_space):
                                  (1, 2000), (1000, 1000)])
 @pytest.mark.parametrize("key_space", [3, 1 << 20])  # dup density sweep
 def test_merge_gather_sweep(n, m, key_space, rng):
-    """Merge-path kernel == ref oracle across sizes × duplicate densities."""
+    """Device merge == ref oracle across sizes × duplicate densities."""
     ah, al = _sorted_pair_run(rng, n, key_space)
     bh, bl = _sorted_pair_run(rng, m, key_space)
     args = tuple(map(jnp.asarray, (ah, al, bh, bl)))
@@ -308,21 +326,15 @@ def test_merge_gather_masked_compaction(n, m, tombstone_ratio, rng):
                                  (4096, 256), (2000, 2000)])
 @pytest.mark.parametrize("key_space", [3, 50, 1 << 20])  # dup density sweep
 def test_merge_gather_partitioned_sweep(n, m, key_space, rng):
-    """Diagonal-partitioned merge == ref oracle across sizes x dup density.
+    """Device merge == ref oracle at multi-hundred-row runs x dup density.
 
-    Runs the partitioned kernel directly at a small block (256) so every
-    case crosses several tile boundaries — including boundaries that land
-    inside long duplicate-key runs, where the split search's stable
-    A-before-B rule must agree with the per-element searches on both
-    sides of the cut.
+    Long duplicate-key runs on both sides are where the stable
+    A-before-B rule (B rows search with side='right') must hold.
     """
-    from repro.kernels.merge_sorted import merge_path_partitioned_pallas
-
     ah, al = _sorted_pair_run(rng, n, key_space)
     bh, bl = _sorted_pair_run(rng, m, key_space)
     args = tuple(map(jnp.asarray, (ah, al, bh, bl)))
-    got = np.asarray(merge_path_partitioned_pallas(
-        *args, block=256, interpret=True))[: n + m]
+    got = np.asarray(ops.merge_gather(*args))
     want = np.asarray(ref.ref_merge_sorted(*args))
     np.testing.assert_array_equal(got, want)
 
@@ -331,11 +343,10 @@ def test_merge_gather_partitioned_sweep(n, m, key_space, rng):
 @pytest.mark.parametrize("tombstone_ratio", [0.0, 0.3, 1.0])
 def test_merge_gather_partitioned_masked_compaction(n, m, tombstone_ratio,
                                                     rng):
-    """Partitioned merge + tombstone drop == host merge of filtered runs.
+    """Device merge + tombstone drop == host merge of filtered runs.
 
-    The device-compaction contract (core/delta.py) re-pinned on the
-    dispatch path that selects the partitioned kernel (both runs >= the
-    1024 default block), across tombstone ratios including kill-everything.
+    The device-compaction contract (core/delta.py) re-pinned at runs past
+    one compaction tile, across tombstone ratios including kill-everything.
     """
     from repro.core.index import merge_sorted
 
@@ -354,7 +365,7 @@ def test_merge_gather_partitioned_masked_compaction(n, m, tombstone_ratio,
     gidx = np.asarray(ops.merge_gather(
         *map(jnp.asarray, (a_rows[:, 1], a_rows[:, 2],
                            b_rows[:, 1], b_rows[:, 2]))))
-    assert ops.pass_counters["merge_partitioned"] >= 1  # dispatch took it
+    assert ops.pass_counters["merge"] >= 1  # traced the device merge
     alive = np.asarray(ops.two_source_gather(
         jnp.asarray(a_alive), jnp.asarray(b_alive), jnp.asarray(gidx)))
     n_live = int(a_alive.sum() + b_alive.sum())
@@ -372,14 +383,11 @@ def test_merge_gather_partitioned_masked_compaction(n, m, tombstone_ratio,
        st.integers(0, 2**31 - 2))
 @settings(max_examples=20, deadline=None)
 def test_merge_gather_partitioned_property(n, m, seed):
-    from repro.kernels.merge_sorted import merge_path_partitioned_pallas
-
     rng = np.random.default_rng(seed)
     ah, al = _sorted_pair_run(rng, n, int(rng.integers(2, 1 << 16)))
     bh, bl = _sorted_pair_run(rng, m, int(rng.integers(2, 1 << 16)))
     args = tuple(map(jnp.asarray, (ah, al, bh, bl)))
-    got = np.asarray(merge_path_partitioned_pallas(
-        *args, block=256, interpret=True))[: n + m]
+    got = np.asarray(ops.merge_gather(*args))
     np.testing.assert_array_equal(got, np.asarray(ref.ref_merge_sorted(*args)))
 
 

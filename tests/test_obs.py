@@ -77,23 +77,23 @@ def test_pass_counters_thread_safe_and_mirrored():
     before = ops.reset_pass_counters()
     assert set(before) == set(ops.pass_counters)
     assert all(v == 0 for v in ops.pass_counters.values())
-    mirror0 = REGISTRY.counter_value("kernels/passes", kind="merge_resident")
+    mirror0 = REGISTRY.counter_value("kernels/passes", kind="merge")
 
     def worker():
         for _ in range(500):
-            ops._bump_pass("merge_resident")
+            ops._bump_pass("merge")
 
     threads = [threading.Thread(target=worker) for _ in range(8)]
     for t in threads:
         t.start()
     for t in threads:
         t.join()
-    assert ops.pass_counters["merge_resident"] == 4000
-    assert (REGISTRY.counter_value("kernels/passes", kind="merge_resident")
+    assert ops.pass_counters["merge"] == 4000
+    assert (REGISTRY.counter_value("kernels/passes", kind="merge")
             - mirror0) == 4000
     snap = ops.reset_pass_counters()
-    assert snap["merge_resident"] == 4000  # snapshot semantics preserved
-    assert ops.pass_counters["merge_resident"] == 0
+    assert snap["merge"] == 4000  # snapshot semantics preserved
+    assert ops.pass_counters["merge"] == 0
 
 
 def test_histogram_sketch_accuracy_and_summary():

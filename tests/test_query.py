@@ -107,15 +107,14 @@ def test_inl_join_object_probe(lubm_kb):
 
 
 def test_rewrite_dual_branch_is_one_pass(lubm_kb):
-    """(?x rdf:type Person) has dom AND rng branches: ONE fused member pass.
+    """(?x rdf:type Person) has dom AND rng branches: ONE dual-mask pass.
 
     Person entails through domain properties (memberOf, advisor, ...) and
     range properties (member, publicationAuthor) — the dual-branch shape
-    the fused member-compaction kernel resolves in one grid pass per
-    source, with the member/domain/range id sets resident on-chip instead
-    of materialized as full-store masks.  The trace-time pass counters pin
-    it: >= 1 member pass, zero mask-based dual passes, and at most the
-    single pass DISTINCT's dedup owns; answers stay equal to litemat.
+    the dual-mask compaction kernel resolves in one grid pass per source.
+    The trace-time pass counters pin it: >= 1 dual pass, and at most the
+    single pass DISTINCT's dedup owns (none per branch); answers stay
+    equal to litemat.
     """
     from repro.core.query import QueryEngine
     from repro.kernels import ops
@@ -126,11 +125,9 @@ def test_rewrite_dual_branch_is_one_pass(lubm_kb):
     eng = QueryEngine(kb=K.kb, spo=K.kb.spo, mode="rewrite", dtb=K.dtb)
     ops.compact_indices.clear_cache()
     ops.dual_compact_indices.clear_cache()
-    ops.rewrite_member_compact.clear_cache()
     ops.reset_pass_counters()
     rows, _ = eng.run(q)
-    assert ops.pass_counters["member_compact"] >= 1
-    assert ops.pass_counters["dual_compact"] == 0, ops.pass_counters
+    assert ops.pass_counters["dual_compact"] >= 1, ops.pass_counters
     assert ops.pass_counters["compact"] <= 1, ops.pass_counters
     assert {tuple(r) for r in rows.tolist()} == want
     assert len(want) > 0

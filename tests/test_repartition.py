@@ -337,14 +337,14 @@ def test_pin_version_racing_retire_never_reads_a_dropped_snapshot():
 
 def test_pair_search_empty_table_returns_zeros():
     """INL probes against a 0-row source (an ingested store keeps ALL rows
-    in the delta log, base n=0) must yield empty ranges, not a 0-width
-    kernel launch."""
+    in the delta log, base n=0) must yield empty ranges, not a gather
+    from an empty table."""
     empty = jnp.zeros((0,), jnp.int32)
     q = jnp.asarray(np.array([3, 7, 11], np.int32))
     got = np.asarray(ops.pair_search(empty, empty, q, q))
     assert np.array_equal(got, np.zeros(3, np.int32))
-    got_w = np.asarray(ops.pair_search_windowed(empty, empty, q, q))
-    assert np.array_equal(got_w, np.zeros(3, np.int32))
+    got_hi = np.asarray(ops.pair_search(empty, empty, q, q + 1))
+    assert np.array_equal(got_hi, np.zeros(3, np.int32))
 
 
 def test_ingested_store_survives_inl_plans():

@@ -314,20 +314,17 @@ def test_sharded_serving_matches_single(sharded_pair):
 
 
 def test_windowed_inl_probe_parity(sharded_pair):
-    """Force the windowed pair search under the INL join: results must not
-    change (the last whole-table VMEM residency, now size-dispatched)."""
+    """The INL join's pair-search probes must answer exactly what
+    evaluate-then-join answers: Q4 with INL on equals Q4 with INL off."""
     from repro.core import query as qmod
 
     K, _, _ = sharded_pair
     pats = PAPER_QUERIES["Q4"]
     sel = _sel(pats)
-    want, _ = K.query(pats, select=sel, mode="litemat")
-    old = qmod.INL_RESIDENT_MAX
-    qmod.INL_RESIDENT_MAX = 1  # every table takes the windowed path
-    try:
-        eng = qmod.QueryEngine(kb=K.kb, spo=K.lite_spo, mode="litemat",
-                               dtb=K.dtb)
-        got_rel = eng.run(pats, select=sel)
-        assert np.array_equal(want, got_rel[0])
-    finally:
-        qmod.INL_RESIDENT_MAX = old
+    eng = qmod.QueryEngine(kb=K.kb, spo=K.lite_spo, mode="litemat",
+                           dtb=K.dtb)
+    want, _ = eng.run(pats, select=sel)
+    eng.use_inl = False
+    got, _ = eng.run(pats, select=sel)
+    assert np.array_equal(want, got)
+    assert want.shape[0] > 0
