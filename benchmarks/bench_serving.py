@@ -116,7 +116,8 @@ def _tracer_cost_s(n_spans: int, iters: int = 200) -> float:
     t0 = time.perf_counter()
     for _ in range(iters):
         tr = cal.new_trace("cal")
-        root = cal.start_root(tr, "request", n_patterns=3, mode="default")
+        root = cal.start_root(tr, "serving.request", n_patterns=3,
+                              mode="default")
         with activate(root):
             for _ in range(max(n_spans - 1, 0)):
                 with span("s", attempt=0) as sp:

@@ -111,7 +111,7 @@ def main(argv=None) -> int:
     print(f"top {args.top} slowest spans "
           f"({len(traces)} traces, {doc.get('dropped', 0)} dropped):")
     for dur, tid, s in slow_spans(traces, args.top):
-        print(f"  {dur * 1e3:9.3f} ms  {s['name']:<14} {tid}"
+        print(f"  {dur * 1e3:9.3f} ms  {s['name']:<18} {tid}"
               f"{_fmt_attrs(s)}")
     return 0
 

@@ -271,14 +271,12 @@ class DeviceStoreCache:
         }
 
     def _stat(self, key: str, n: int = 1) -> None:
-        """Bump the local dict AND the process registry mirror.
-
-        Row-unit upload counters also feed ``device/transfer_bytes``
-        (12 B per [s,p,o] int32 row, 1 B per liveness bit) so the
-        observability layer sees host->device traffic in one unit.
+        """Bump the local dict; row-unit upload counters also feed
+        ``device/transfer_bytes`` in the process registry (12 B per
+        [s,p,o] int32 row, 1 B per liveness bit) so the observability
+        layer sees host->device traffic in one unit.
         """
         self.stats[key] += n
-        REGISTRY.counter("device/" + key, src="store_cache").inc(n)
         if key == "upload_delta_rows":
             REGISTRY.counter("device/transfer_bytes",
                              src="store_cache").inc(n * 12)

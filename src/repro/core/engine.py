@@ -31,7 +31,6 @@ from __future__ import annotations
 
 import contextvars
 import threading
-import time
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -54,7 +53,6 @@ from repro.core.update import (
     encode_delta, materialize_delta_mode, mention_rows, mentions_mask,
 )
 from repro.obs import trace as obs_trace
-from repro.obs.metrics import REGISTRY
 from repro.rdf.generator import RawDataset
 from repro.testing import faults
 from repro.utils.parallel import run_concurrently
@@ -184,25 +182,20 @@ class KnowledgeBase:
             todo.append((mode, cur))
 
         def derive(mode, cur):
-            t0 = time.perf_counter()
-            with obs_trace.span("flush_mat", mode=mode, n_batches=n - cur):
-                rows = [materialize_delta_mode(spo, self.dtb, mode)
+            with obs_trace.span("engine.flush", mode=mode, n_batches=n - cur):
+                return [materialize_delta_mode(spo, self.dtb, mode)
                         for spo in self._pending_raw[cur:]]
-            return rows, time.perf_counter() - t0
 
         # each thread derives inside a copy of this context: its span joins
         # the calling request's trace
         results = run_concurrently([
             partial(contextvars.copy_context().run, derive, m, c)
             for m, c in todo])
-        for (mode, _), (derived, secs) in zip(todo, results):
+        for (mode, _), derived in zip(todo, results):
             for rows in derived:
                 self.delta.log(mode).append(rows)
                 self.mat_counts[mode] += 1
             self._mat_cursor[mode] = n
-            REGISTRY.histogram("engine/flush_s", mode=mode).observe(secs)
-            REGISTRY.counter("engine/derived_rows", mode=mode).inc(
-                sum(int(r.shape[0]) for r in derived))
         if fault is not None:
             raise fault
         if self._pending_raw and all(
@@ -416,7 +409,6 @@ class KnowledgeBase:
                 self._flush_mat("litemat", "full")
             d.n_new_terms += n_new
             self._bump()
-            REGISTRY.counter("engine/inserted_rows").inc(int(spo.shape[0]))
             stats = dict(
                 n_inserted=int(spo.shape[0]),
                 n_new_terms=n_new,
@@ -541,8 +533,6 @@ class KnowledgeBase:
             for mode, rows in zip(("litemat", "full"), derived):
                 self.append_derived(mode, rows[mentions_mask(rows, inst)])
             self._bump()
-            REGISTRY.counter("engine/deleted_rows").inc(
-                int(deleted.shape[0]))
             stats = dict(
                 n_deleted=int(deleted.shape[0]),
                 n_affected_instances=int(inst.shape[0]),
@@ -572,8 +562,7 @@ class KnowledgeBase:
             if ((self._delta is None or self._delta.empty)
                     and not self._pending_raw):
                 return dict(compacted=False)
-            with obs_trace.span("compact"):
-                t0 = time.perf_counter()
+            with obs_trace.span("engine.compact"):
                 self._flush_mat("litemat", "full")
                 if device is None:
                     device = jax.default_backend() == "tpu"
@@ -593,7 +582,4 @@ class KnowledgeBase:
                 self._delta = DeltaKB()
                 self._raw_loc = None
                 self._bump()
-                REGISTRY.counter("engine/compactions").inc()
-                REGISTRY.histogram("engine/compact_s").observe(
-                    time.perf_counter() - t0)
             return dict(compacted=True, version=self.version, **sizes)

@@ -59,7 +59,7 @@ import hashlib
 import threading
 import time
 from dataclasses import dataclass, field
-from functools import partial
+from functools import partial, wraps
 
 import numpy as np
 
@@ -864,13 +864,14 @@ class QueryEngine:
         return sig, dyn, sum(lens)
 
     def _pattern_count(self, sig: PatternSig, dyn) -> int:
-        """Planning cardinality of a scan pattern (cached jitted reduction)."""
+        """Planning cardinality of a scan pattern (cached jitted reduction):
+        one device round trip, counted in ``query/plan_syncs``."""
         if self.view.n == 0:  # empty store (e.g. a fresh shard): no device pass
             return 0
         key = ("count", sig)
         fn = self._exec_cache.get(key)
         if fn is None:
-            def count_device(ds, d, _sig=sig):
+            def plan_count(ds, d, _sig=sig):
                 sources = [(ds.base, ds.base_alive)]
                 if ds.delta is not None:
                     sources.append((ds.delta, ds.delta_alive))
@@ -888,9 +889,11 @@ class QueryEngine:
                         m, _ = _scan_mask(_sig, spo, alive, d)
                         total += m.astype(jnp.int32).sum()
                 return total
-            fn = jax.jit(count_device)
+            fn = jax.jit(plan_count)
             self._exec_cache[key] = fn
-        return int(fn(self.view.dev("scan"), dyn))
+        REGISTRY.counter("query/plan_syncs").inc()
+        with obs_trace.span("query.plan.count", strategy=sig.strategy):
+            return int(fn(self.view.dev("scan"), dyn))
 
     @staticmethod
     def _make_run_device(sigs, caps, join_cap: int, select):
@@ -905,7 +908,7 @@ class QueryEngine:
         row counts off it.
         """
 
-        def run_device(stores, dyns):
+        def query_exec(stores, dyns):
             rel = None
             totals = []
             for sig, cap, dyn in zip(sigs, caps, dyns):
@@ -919,10 +922,10 @@ class QueryEngine:
             return (out.cols, out.valid, out.overflow,
                     jnp.stack(totals).astype(jnp.int32))
 
-        return run_device
+        return query_exec
 
     @staticmethod
-    def _timed_compile(fn, label: str, kind: str):
+    def _timed_compile(fn, label: str):
         """Wrap a fresh jitted plan so its FIRST call — the one that pays
         trace+compile — is timed into ``query/compile_seconds{sig=}``.
 
@@ -933,6 +936,7 @@ class QueryEngine:
         """
         state = {"pending": True}
 
+        @wraps(fn)
         def wrapper(*args):
             if not state["pending"]:
                 return fn(*args)
@@ -940,10 +944,8 @@ class QueryEngine:
             t0 = time.perf_counter()
             out = fn(*args)
             jax.block_until_ready(out)
-            dt = time.perf_counter() - t0
-            REGISTRY.counter("query/compiles", sig=label, kind=kind).inc()
-            REGISTRY.histogram("query/compile_seconds",
-                               sig=label).observe(dt)
+            REGISTRY.histogram("query/compile_seconds", sig=label).observe(
+                time.perf_counter() - t0)
             return out
 
         return wrapper
@@ -956,7 +958,7 @@ class QueryEngine:
             fn = self._exec_cache.get(key)
             hit = fn is not None
             if not hit:
-                fn = self._timed_compile(jax.jit(make()), slabel, kind)
+                fn = self._timed_compile(jax.jit(make()), slabel)
                 self._exec_cache[key] = fn
             self.cache_stats["hits" if hit else "misses"] += 1
         event = ("hit" if hit else "miss") + ("_batch" if kind == "batch"
@@ -979,10 +981,17 @@ class QueryEngine:
         path, pair search) lifts through ``jax.vmap``, so a batch of B
         same-signature requests costs ONE XLA dispatch instead of B.
         """
-        return self._memo(key, sig_label(sigs), "batch",
-                          lambda: jax.vmap(self._make_run_device(
-                              sigs, caps, join_cap, select),
-                              in_axes=(None, 0)))
+        def make():
+            batched = jax.vmap(self._make_run_device(sigs, caps, join_cap,
+                                                     select),
+                               in_axes=(None, 0))
+
+            def query_exec_batch(stores, dyns):
+                return batched(stores, dyns)
+
+            return query_exec_batch
+
+        return self._memo(key, sig_label(sigs), "batch", make)
 
     @staticmethod
     def _bucket(n: int) -> int:
@@ -1120,19 +1129,12 @@ class QueryEngine:
                         inl_rows = max(int(round(obs * store_n)), 1)
                         convert = inl_rows * self.inl_factor <= counts[i]
                         sized = max(inl_rows * 2, max(est, 1) * 2)
-                        src = "observed"
                     else:
                         convert = heuristic
                         sized = max(est, 1) * 32
-                        src = "estimate"
                     if convert:
-                        REGISTRY.counter("planner/inl_decision",
-                                         source=src).inc()
                         counts[i] = min(counts[i], sized)
                         lowered[i] = (sig, dyn, counts[i])
-                    elif src == "observed" and heuristic:
-                        REGISTRY.counter("planner/inl_decision",
-                                         source="observed_veto").inc()
             bound |= pat_vars
             ctx.append(ckeys[i])
             est = min(est, counts[i])
@@ -1149,91 +1151,89 @@ class QueryEngine:
         order — pattern j's bucket is the const keys of plan positions
         0..j, the key half that de-aliases ``observed_selectivity``.
         """
-        prepared = self._prepare(patterns)
-        lowered = [self._lower(*pre) for pre in prepared]
-        counts = [
-            c if c is not None else self._pattern_count(sig, dyn)
-            for sig, dyn, c in lowered
-        ]
-        ckeys = [_pattern_const_key(pre[1]) for pre in prepared]
-        order = self._plan_order(prepared, counts)
-        self._apply_inl(prepared, lowered, counts, order, ckeys)
-        caps = [self._bucket(int(counts[i] * self.slack) + 16) for i in order]
-        join_cap = self._bucket(int(max(counts) * self.slack) + 16)
+        with obs_trace.span("query.plan", mode=self.mode,
+                            n_patterns=len(patterns)):
+            prepared = self._prepare(patterns)
+            lowered = [self._lower(*pre) for pre in prepared]
+            counts = [
+                c if c is not None else self._pattern_count(sig, dyn)
+                for sig, dyn, c in lowered
+            ]
+            ckeys = [_pattern_const_key(pre[1]) for pre in prepared]
+            order = self._plan_order(prepared, counts)
+            self._apply_inl(prepared, lowered, counts, order, ckeys)
+            caps = [self._bucket(int(counts[i] * self.slack) + 16)
+                    for i in order]
+            join_cap = self._bucket(int(max(counts) * self.slack) + 16)
 
-        sigs = tuple(lowered[i][0] for i in order)
-        dyns = tuple(lowered[i][1] for i in order)
-        all_vars = tuple(dict.fromkeys(
-            v for sig in sigs for v in sig.pvars if v is not None))
-        sel = tuple(select) if select else all_vars
-        buckets = tuple(tuple(ckeys[i] for i in order[: j + 1])
-                        for j in range(len(order)))
-        return (sigs, dyns, caps, join_cap, sel, self._stores(sigs),
-                tuple(order), tuple(counts[i] for i in order), buckets)
+            sigs = tuple(lowered[i][0] for i in order)
+            dyns = tuple(lowered[i][1] for i in order)
+            all_vars = tuple(dict.fromkeys(
+                v for sig in sigs for v in sig.pvars if v is not None))
+            sel = tuple(select) if select else all_vars
+            buckets = tuple(tuple(ckeys[i] for i in order[: j + 1])
+                            for j in range(len(order)))
+            return (sigs, dyns, caps, join_cap, sel, self._stores(sigs),
+                    tuple(order), tuple(counts[i] for i in order), buckets)
 
-    def _record_observed(self, sigs, est, totals, buckets) -> None:
-        """Land observed per-pattern row counts in the process registry.
+    def _record_observed(self, sigs, totals, buckets) -> None:
+        """Land observed per-pattern selectivities for the planner.
 
         ``observed_selectivity`` (engine-local, keyed by ``(PatternSig,
         probe-constant bucket)``) is the exact read-back surface for the
-        planner; the registry histograms aggregate observed rows and
-        estimate error (est/obs ratio) by strategy for the exporters and
-        the ROADMAP item-1 batcher.
+        planner; the ``planner/selectivity`` gauge keeps the last one per
+        strategy and store for operators.
         """
         store_n = max(self.view.n, 1)
-        for sig, e, obs, bucket in zip(sigs, est, totals, buckets):
+        for sig, obs, bucket in zip(sigs, totals, buckets):
             obs = int(obs)
             self.observed_selectivity[(sig, bucket)] = obs / store_n
-            REGISTRY.histogram("planner/observed_rows",
-                               strategy=sig.strategy).observe(obs)
-            REGISTRY.histogram("planner/est_ratio",
-                               strategy=sig.strategy).observe(
-                (int(e) + 1) / (obs + 1))
             REGISTRY.gauge("planner/selectivity", strategy=sig.strategy,
                            store=sig.store).set(obs / store_n)
 
     def run(self, patterns, select=None, max_retries: int = 6):
         """Execute; returns (rows int32[k, n_select], select var names)."""
-        with obs_trace.span("plan", mode=self.mode,
-                            n_patterns=len(patterns)):
-            planned = self._plan(patterns, select)
-        return self._run_planned(planned, max_retries)
+        return self._run_planned(self._plan(patterns, select), max_retries)
 
     def _run_planned(self, planned, max_retries: int = 6):
         """Execute an already-planned query (the solo dispatch path)."""
-        (sigs, dyns, caps, join_cap, sel, stores, order, est,
-         buckets) = planned
-        slabel = sig_label(sigs)
-        for attempt in range(max_retries):
-            key = ("exec", self.mode, sigs, tuple(caps), join_cap, sel)
-            misses0 = self.cache_stats["misses"]
-            fn = self._executable(key, sigs, tuple(caps), join_cap, sel)
-            with obs_trace.span("dispatch",
-                                cached=self.cache_stats["misses"] == misses0,
-                                join_cap=join_cap) as dsp:
+        with obs_trace.span("query.execute"):
+            sigs, dyns, caps, join_cap, sel, stores, _, _, buckets = planned
+            slabel = sig_label(sigs)
+            for attempt in range(max_retries):
+                key = ("exec", self.mode, sigs, tuple(caps), join_cap, sel)
+                misses0 = self.cache_stats["misses"]
+                fn = self._executable(key, sigs, tuple(caps), join_cap, sel)
                 t0 = time.perf_counter()
-                cols, valid, overflow, totals = fn(stores, dyns)
-                done = int(overflow) == 0  # blocks on the dispatch
-                REGISTRY.histogram("query/exec_seconds", sig=slabel).observe(
+                cached = self.cache_stats["misses"] == misses0
+                with obs_trace.span("query.dispatch", cached=cached,
+                                    join_cap=join_cap) as dsp:
+                    cols, valid, overflow, totals = fn(stores, dyns)
+                with obs_trace.span("query.device_wait"):
+                    done = int(overflow) == 0
+                REGISTRY.histogram("query/exec_seconds",
+                                   sig=slabel).observe(
                     time.perf_counter() - t0)
                 dsp.set_attr(overflow=not done)
-            if done:
-                if attempt:
-                    REGISTRY.histogram("join/capacity_depth", site="query",
-                                       sig=slabel,
-                                       shard="local").observe(attempt)
-                self._record_observed(sigs, est, np.asarray(totals), buckets)
-                n = int(valid.sum())
-                rows = np.asarray(cols)[:, :n].T
-                return rows, sel
-            obs_trace.event("overflow_retry", attempt=attempt,
-                            join_cap=join_cap)
-            REGISTRY.counter("query/overflow_retries").inc()
-            REGISTRY.counter("join/capacity_retry", site="query", sig=slabel,
-                             shard="local").inc()
-            join_cap *= 2
-            caps = [c * 2 for c in caps]
-        raise RuntimeError("query kept overflowing its capacity buckets")
+                if done:
+                    if attempt:
+                        REGISTRY.histogram("join/capacity_depth",
+                                           site="query", sig=slabel,
+                                           shard="local").observe(attempt)
+                    with obs_trace.span("query.fetch") as fetch:
+                        self._record_observed(sigs, np.asarray(totals),
+                                              buckets)
+                        n = int(valid.sum())
+                        rows = np.asarray(cols)[:, :n].T
+                        fetch.set_attr(rows=n)
+                    return rows, sel
+                dsp.add_event("overflow_retry", attempt=attempt,
+                              join_cap=join_cap)
+                REGISTRY.counter("join/capacity_retry", site="query",
+                                 sig=slabel, shard="local").inc()
+                join_cap *= 2
+                caps = [c * 2 for c in caps]
+            raise RuntimeError("query kept overflowing its capacity buckets")
 
     # -- micro-batched execution (ROADMAP item 1) ---------------------------
     def _batch_caps(self, planned_group):
@@ -1313,7 +1313,6 @@ class QueryEngine:
                         + [entries[-1][0][1]] * (Bp - B))
             dyn_stack = jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs), *dyn_list)
-            REGISTRY.histogram("query/batch_size", mode=self.mode).observe(B)
             slabel = sig_label(sigs)
             for attempt in range(max_retries):
                 key = ("bexec", self.mode, sigs, tuple(caps), join_cap,
@@ -1321,8 +1320,11 @@ class QueryEngine:
                 fn = self._batch_executable(key, sigs, tuple(caps),
                                             join_cap, sel)
                 t0 = time.perf_counter()
-                cols, valid, overflow, totals = fn(stores, dyn_stack)
-                ok = int(np.asarray(overflow)[:B].max()) == 0
+                with obs_trace.span("query.dispatch", join_cap=join_cap,
+                                    batch=B) as dsp:
+                    cols, valid, overflow, totals = fn(stores, dyn_stack)
+                with obs_trace.span("query.device_wait"):
+                    ok = int(np.asarray(overflow)[:B].max()) == 0
                 REGISTRY.histogram("query/exec_seconds", sig=slabel).observe(
                     time.perf_counter() - t0)
                 if ok:
@@ -1331,9 +1333,8 @@ class QueryEngine:
                             "join/capacity_depth", site="batch", sig=slabel,
                             shard="local").observe(attempt)
                     break
-                obs_trace.event("overflow_retry", attempt=attempt,
-                                join_cap=join_cap, batch=B)
-                REGISTRY.counter("query/overflow_retries").inc()
+                dsp.add_event("overflow_retry", attempt=attempt,
+                              join_cap=join_cap, batch=B)
                 REGISTRY.counter("join/capacity_retry", site="batch",
                                  sig=slabel, shard="local").inc()
                 join_cap *= 2
@@ -1341,12 +1342,12 @@ class QueryEngine:
             else:
                 raise RuntimeError(
                     "batched query kept overflowing its capacity buckets")
-            cols_h = np.asarray(cols)
-            valid_h = np.asarray(valid)
-            totals_h = np.asarray(totals)
+            with obs_trace.span("query.fetch", batch=B):
+                cols_h = np.asarray(cols)
+                valid_h = np.asarray(valid)
+                totals_h = np.asarray(totals)
             for b, (planned, members) in enumerate(entries):
-                self._record_observed(sigs, planned[7], totals_h[b],
-                                      planned[8])
+                self._record_observed(sigs, totals_h[b], planned[8])
                 n = int(valid_h[b].sum())
                 rows = cols_h[b][:, :n].T
                 for i in members:
@@ -1374,7 +1375,7 @@ class QueryEngine:
             cols, valid, overflow, totals = fn(stores, dyns)
             observed = [int(t) for t in np.asarray(totals)]
             n_rows = int(valid.sum())
-            self._record_observed(sigs, est, observed, buckets)
+            self._record_observed(sigs, observed, buckets)
             # observed hot-key skew: for every join variable we can read
             # off the result (selected + shared by >= 2 patterns), how
             # lopsided is the per-key row distribution?  This is the
@@ -1399,8 +1400,6 @@ class QueryEngine:
                         "mean_rows_per_key": mean,
                         "skew": top / mean,
                     }
-                    REGISTRY.gauge("join/hot_key_skew", var=v,
-                                   sig=sig_label(sigs)).set(top / mean)
         store_n = max(self.view.n, 1)
         pats = []
         for j, sig in enumerate(sigs):

@@ -350,10 +350,8 @@ class ShardedKB:
                         report.parts.append(dict(
                             part=k, ok=False, attempts=attempt + 1,
                             error=f"{type(e).__name__}: {e}"))
-                        REGISTRY.counter("shard/ingest_failed_parts").inc()
                         break
                     report.n_retries += 1
-                    REGISTRY.counter("shard/ingest_retries").inc()
                     delay = min(backoff_cap_s, backoff_s * (2 ** attempt))
                     time.sleep(delay * (0.5 + 0.5 * rng.random()))
                     attempt += 1
@@ -495,8 +493,7 @@ class ShardedKB:
             cur = self._mat_cursor[mode]
             if cur >= n:
                 continue
-            t0 = time.perf_counter()
-            with obs_trace.span("flush_mat", mode=mode, n_batches=n - cur,
+            with obs_trace.span("engine.flush", mode=mode, n_batches=n - cur,
                                 sharded=True):
                 staged = []
                 for b, parts in enumerate(self._pending[cur:]):
@@ -511,19 +508,13 @@ class ShardedKB:
                             derived_src.append(
                                 materialize_delta_mode(part, self.dtb, mode))
                     staged.append(_exchange(derived_src, self.n_shards))
-                derived_rows = 0
                 for exchanged in staged:
                     for j, rows in enumerate(exchanged):
                         self.shards[j].append_derived(mode, rows)
-                        derived_rows += int(rows.shape[0])
                     self.mat_counts[mode] += 1
                 self._mat_cursor[mode] = n
                 for K in self.shards:
                     K._bump()
-            REGISTRY.histogram("shard/flush_s", mode=mode).observe(
-                time.perf_counter() - t0)
-            REGISTRY.counter("shard/derived_rows", mode=mode).inc(
-                derived_rows)
         if self._pending and all(
                 c >= n for c in self._mat_cursor.values()):
             self._pending.clear()
@@ -657,8 +648,7 @@ class ShardedKB:
             if (all(K._delta is None or K._delta.empty for K in self.shards)
                     and not self._pending):
                 return dict(compacted=False)
-            t0 = time.perf_counter()
-            with obs_trace.span("compact", sharded=True,
+            with obs_trace.span("engine.compact", sharded=True,
                                 n_shards=self.n_shards):
                 self._flush("litemat", "full")
                 sizes = {m: 0 for m in MODES}
@@ -668,9 +658,6 @@ class ShardedKB:
                     for m in MODES:
                         sizes[m] += int(out.get(m, 0))
                 self.version += 1
-            REGISTRY.counter("shard/compactions").inc()
-            REGISTRY.histogram("shard/compact_s").observe(
-                time.perf_counter() - t0)
             return dict(compacted=True, version=self.version, **sizes)
 
     # -- query surface -------------------------------------------------------
@@ -959,7 +946,6 @@ class ShardStack:
 
         if st is None or st["ncap"] != ncap or st["tokens"] != tokens:
             self.stats["base_rebuilds"] += 1
-            REGISTRY.counter("device/base_rebuilds", src="shard_stack").inc()
             base = np.full((S, ncap, 3), np.iinfo(np.int32).max, np.int32)
             alive = np.zeros((S, ncap), bool)
             for i, v in enumerate(views):
@@ -972,8 +958,6 @@ class ShardStack:
                           else v.base_alive_h[v.base_index.perm(key).perm])
                     alive[i, :ah.shape[0]] = ah
                 self.stats["upload_base_rows"] += int(h.shape[0])
-                REGISTRY.counter("device/upload_rows", src="shard_stack",
-                                 kind="base").inc(int(h.shape[0]))
                 REGISTRY.counter("device/transfer_bytes",
                                  src="shard_stack").inc(int(h.nbytes))
             st = {"ncap": ncap, "tokens": tokens,
@@ -995,8 +979,6 @@ class ShardStack:
                         i, jnp.asarray(full.astype(np.int32))].set(
                         False, mode="drop"))
                     self.stats["kill_scatter_rows"] += int(idx.shape[0])
-                    REGISTRY.counter("device/kill_scatter_rows",
-                                     src="shard_stack").inc(int(idx.shape[0]))
                     st["n_kills"][i] = len(v.kills)
 
         dstate = [(v.delta_n, v.delta_mut) for v in views]
@@ -1014,8 +996,6 @@ class ShardStack:
                     drows[i, :rows.shape[0]] = rows
                     dalive[i, :al.shape[0]] = al
                     self.stats["upload_delta_rows"] += dcap
-                    REGISTRY.counter("device/upload_rows", src="shard_stack",
-                                     kind="delta").inc(dcap)
                     REGISTRY.counter("device/transfer_bytes",
                                      src="shard_stack").inc(dcap * 12)
                 st["delta"] = self._put(drows)
@@ -1120,10 +1100,9 @@ class ShardedQueryEngine:
     def _run_group_loop(self, gpats, gvars):
         """Per-shard dispatch: each shard's own engine runs the group plan."""
         self.cache_stats["loop_runs"] += 1
-        REGISTRY.counter("shard/group_runs", path="loop").inc()
         engines = self._engines()
         parts = []
-        with obs_trace.span("shard_dispatch", path="loop",
+        with obs_trace.span("shard.dispatch", path="loop",
                             n_shards=self.skb.n_shards):
             for i in self._route_shards(gpats):
                 if self.skb.shards[i].view(self.mode).n == 0:
@@ -1209,7 +1188,6 @@ class ShardedQueryEngine:
                                        site="shard_map",
                                        sig=slabel).observe(attempt)
                 self.cache_stats["shard_map_runs"] += 1
-                REGISTRY.counter("shard/group_runs", path="shard_map").inc()
                 return cols, valid
             # overflow is per shard: attribute the retry to each shard
             # whose buckets burst — lopsided counters here are the
@@ -1239,10 +1217,8 @@ class ShardedQueryEngine:
         fn = self._exec_cache.get(key)
         if fn is not None:
             self.cache_stats["hits"] += 1
-            REGISTRY.counter("shard/exec_cache", event="hit").inc()
             return fn
         self.cache_stats["misses"] += 1
-        REGISTRY.counter("shard/exec_cache", event="miss").inc()
 
         def body(stores, dyns):
             st1 = {k: DevStore(
@@ -1273,7 +1249,7 @@ class ShardedQueryEngine:
     def _run_group(self, gpats, gvars):
         if self._shard_map_on():
             try:
-                with obs_trace.span("shard_dispatch", path="shard_map",
+                with obs_trace.span("shard.dispatch", path="shard_map",
                                     n_shards=self.skb.n_shards) as sp:
                     faults.fire("shard.shard_map")
                     parts = self._run_group_shard_map(gpats, gvars)
@@ -1308,10 +1284,8 @@ class ShardedQueryEngine:
         fn = self._exec_cache.get(ck)
         if fn is not None:
             self.cache_stats["hits"] += 1
-            REGISTRY.counter("shard/exec_cache", event="hit").inc()
             return fn
         self.cache_stats["misses"] += 1
-        REGISTRY.counter("shard/exec_cache", event="miss").inc()
         S = self.skb.n_shards
         ai, ri = acc_vars.index(key), rel_vars.index(key)
 
@@ -1363,10 +1337,8 @@ class ShardedQueryEngine:
         fn = self._exec_cache.get(ck)
         if fn is not None:
             self.cache_stats["hits"] += 1
-            REGISTRY.counter("shard/exec_cache", event="hit").inc()
             return fn
         self.cache_stats["misses"] += 1
-        REGISTRY.counter("shard/exec_cache", event="miss").inc()
         S = self.skb.n_shards
 
         def local(c, v):
@@ -1400,7 +1372,7 @@ class ShardedQueryEngine:
         fold, exactly like the single-group dispatch does.
         """
         evaluated = []
-        with obs_trace.span("shard_combine", path="repartition",
+        with obs_trace.span("shard.combine", path="repartition",
                             n_groups=len(groups)):
             for g in groups:
                 gpats = [patterns[i] for i in g]

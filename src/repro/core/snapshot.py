@@ -34,7 +34,6 @@ pinning is cheap: no recompilation, no buffer copies, just refcounts.
 from __future__ import annotations
 
 import threading
-import time
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -141,7 +140,7 @@ class Snapshot:
         engines = self._shard_engines(mode)
         views = self.views[mode]
         evaluated = []
-        with obs_trace.span("shard_dispatch", path="loop",
+        with obs_trace.span("shard.dispatch", path="loop",
                             n_groups=len(groups), n_shards=len(engines)):
             for g in groups:
                 gpats = [patterns[i] for i in g]
@@ -194,7 +193,7 @@ class Snapshot:
                 metas.append(gvars)
             members.append((pats, select, metas, idxs))
         parts_by_flat = [[] for _ in shard_reqs]
-        with obs_trace.span("shard_dispatch", path="batch",
+        with obs_trace.span("shard.dispatch", path="batch",
                             n_groups=len(shard_reqs),
                             n_shards=len(engines)):
             for i, eng in enumerate(engines):
@@ -216,7 +215,8 @@ class Snapshot:
 
     def answers(self, patterns, select=None, mode: str = None) -> set:
         rows, _ = self.query(patterns, select=select, mode=mode)
-        return {tuple(r) for r in rows.tolist()}
+        with obs_trace.span("serving.answers", rows=len(rows)):
+            return {tuple(r) for r in rows.tolist()}
 
     def device_buffers(self) -> list:
         """Device buffers this snapshot's pinned views keep alive.
@@ -334,11 +334,7 @@ class SnapshotRegistry:
 
     def _refresh_gauges_locked(self) -> None:
         """Version/refcount gauges; caller holds self._lock."""
-        m = self.metrics
-        m.gauge("snapshot/live_versions").set(len(self._snaps))
-        m.gauge("snapshot/pinned_versions").set(
-            sum(1 for s in self._snaps.values() if s.refs > 0))
-        m.gauge("snapshot/pinned_refs").set(
+        self.metrics.gauge("snapshot/pinned_refs").set(
             sum(s.refs for s in self._snaps.values()))
 
     # -- capture / publish ---------------------------------------------------
@@ -369,12 +365,9 @@ class SnapshotRegistry:
         with self._lock:
             snap = self._snaps.get(v)
         if snap is None:
-            with obs_trace.span("capture", version=v):
-                t0 = time.perf_counter()
+            with obs_trace.span("snapshot.capture", version=v):
                 faults.fire("snapshot.publish", version=v)
                 views = self._capture()
-                self.metrics.histogram("snapshot/capture_s").observe(
-                    time.perf_counter() - t0)
             snap = Snapshot(version=v, kb=self.kb, modes=self.modes,
                             views=views, use_index=self.use_index,
                             _plan_caches=self._plan_caches,
@@ -404,14 +397,6 @@ class SnapshotRegistry:
     def pin(self, lock_timeout_s: float | None = None) -> Pin:
         """Pin a snapshot for reading; degrade to the last published one
         (stale tag) rather than blocking on a busy writer."""
-        t0 = time.perf_counter()
-        try:
-            return self._pin(lock_timeout_s)
-        finally:
-            self.metrics.histogram("snapshot/pin_wait_s").observe(
-                time.perf_counter() - t0)
-
-    def _pin(self, lock_timeout_s: float | None) -> Pin:
         m = self.metrics
         m.counter("snapshot/pins").inc()
         with self._lock:
@@ -502,7 +487,6 @@ class SnapshotRegistry:
         pin could hit), then each victim is re-checked under the lock
         before removal — a pin that raced in keeps its snapshot.
         """
-        t0 = time.perf_counter()
         with self._lock:
             victims = [v for v, s in self._snaps.items()
                        if s.refs == 0 and s is not self._published]
@@ -519,8 +503,6 @@ class SnapshotRegistry:
             self._refresh_gauges_locked()
         if dropped:
             self.metrics.counter("snapshot/retired").inc(dropped)
-            self.metrics.histogram("snapshot/retire_s").observe(
-                time.perf_counter() - t0)
         return dropped
 
     def device_buffers(self) -> list:

@@ -61,4 +61,5 @@ def closure_expand_pallas(conc, sorted_ids, anc_table, *, block: int = DEFAULT_B
         out_specs=pl.BlockSpec((block, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, D), jnp.int32),
         interpret=interpret,
+        name="closure_expand",
     )(sorted_ids, anc_table, conc)

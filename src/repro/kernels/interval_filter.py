@@ -46,4 +46,5 @@ def interval_filter_pallas(p, o, params, *, block: int = DEFAULT_BLOCK, interpre
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
         interpret=interpret,
+        name="interval_filter",
     )(params, p, o)

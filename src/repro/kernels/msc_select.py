@@ -53,4 +53,5 @@ def msc_select_pallas(conc, bounds, *, group_block: int = DEFAULT_GROUP_BLOCK,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((G, K), jnp.int32),
         interpret=interpret,
+        name="msc_select",
     )(conc, bounds)

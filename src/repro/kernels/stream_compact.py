@@ -157,9 +157,10 @@ def _dual_kernel(ma_ref, mb_ref, idxa_ref, cnta_ref, idxb_ref, cntb_ref):
     _emit(mb_ref[...], idxb_ref, cntb_ref)
 
 
-def _compact_call(kernel, n: int, block: int, n_smem: int, n_tiled: int,
-                  streams: int, interpret: bool):
-    """pallas_call over ``n // block`` tiles of (block // 128, 128) rows.
+def _compact_call(kernel, name: str, n: int, block: int, n_smem: int,
+                  n_tiled: int, streams: int, interpret: bool):
+    """pallas_call ``name`` over ``n // block`` tiles of (block // 128, 128)
+    rows.
 
     Each stream emits its compacted indices tile-for-tile and its count
     broadcast over one (1, 1, 128) block — a rank-1 (1,) block per tile is
@@ -178,6 +179,7 @@ def _compact_call(kernel, n: int, block: int, n_smem: int, n_tiled: int,
         out_shape=[jax.ShapeDtypeStruct((n // LANES, LANES), jnp.int32),
                    jax.ShapeDtypeStruct((nb, 1, LANES), jnp.int32)] * streams,
         interpret=interpret,
+        name=name,
     )
 
 
@@ -199,7 +201,8 @@ def stream_compact_pallas(mask, *, block: int = DEFAULT_BLOCK,
     """mask: int32[N] 0/1 (N a multiple of block) ->
     (tile-compacted global indices int32[N], per-tile counts int32[N/block])."""
     n = mask.shape[0]
-    call = _compact_call(_mask_kernel, n, block, 0, 1, 1, interpret)
+    call = _compact_call(_mask_kernel, "stream_compact", n, block, 0, 1, 1,
+                         interpret)
     return _streams(call(_tiles(mask)))
 
 
@@ -208,7 +211,8 @@ def interval_compact_pallas(p, o, params, *, block: int = DEFAULT_BLOCK,
     """p, o: int32[N]; params: int32[4] = (plo, phi, olo, ohi) ->
     (tile-compacted match indices, per-tile counts) — predicate fused."""
     n = p.shape[0]
-    call = _compact_call(_fused_kernel, n, block, 1, 2, 1, interpret)
+    call = _compact_call(_fused_kernel, "interval_compact", n, block, 1, 2,
+                         1, interpret)
     return _streams(call(params, _tiles(p), _tiles(o)))
 
 
@@ -219,7 +223,8 @@ def masked_interval_compact_pallas(p, o, alive, params, *,
     (tile-compacted match indices, per-tile counts) — interval predicate and
     tombstone filter fused in one pass."""
     n = p.shape[0]
-    call = _compact_call(_masked_fused_kernel, n, block, 1, 3, 1, interpret)
+    call = _compact_call(_masked_fused_kernel, "masked_interval_compact", n,
+                         block, 1, 3, 1, interpret)
     return _streams(call(params, _tiles(p), _tiles(o), _tiles(alive)))
 
 
@@ -232,5 +237,6 @@ def dual_compact_pallas(mask_a, mask_b, *, block: int = DEFAULT_BLOCK,
     so the dual-branch consumer pays one grid pass instead of two.
     """
     n = mask_a.shape[0]
-    call = _compact_call(_dual_kernel, n, block, 0, 2, 2, interpret)
+    call = _compact_call(_dual_kernel, "dual_compact", n, block, 0, 2, 2,
+                         interpret)
     return _streams(call(_tiles(mask_a), _tiles(mask_b)))

@@ -9,8 +9,8 @@ every layer the same three instruments:
   * :class:`Counter` — monotonic, lock-guarded increments (safe under the
     threaded serving workers; a GIL'd ``dict[k] += 1`` is NOT atomic across
     its read/add/store bytecodes).
-  * :class:`Gauge` — last-write-wins point-in-time values (queue depth,
-    live snapshot versions, observed selectivities).
+  * :class:`Gauge` — last-write-wins point-in-time values (admission
+    bound, pinned snapshot refs, observed selectivities).
   * :class:`Histogram` — a log-bucketed sketch: observations land in
     geometric buckets ``GROWTH**i`` (GROWTH = 2^(1/8), ~9% wide), so p50 /
     p99 / mean come from O(#buckets) memory at <= ~4.5% relative value
@@ -22,7 +22,7 @@ use::
 
     REGISTRY.counter("serving/outcomes", status="ok").inc()
     REGISTRY.histogram("serving/latency_s", status="ok").observe(dt)
-    REGISTRY.gauge("serving/queue_depth").set(q.qsize())
+    REGISTRY.gauge("serving/admission_bound").set(bound)
 
 ``MetricsRegistry`` instances are cheap; per-object scopes (one per
 :class:`~repro.serving.runtime.ServingRuntime`, one per

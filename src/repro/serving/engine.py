@@ -47,7 +47,6 @@ from jax.sharding import AxisType, PartitionSpec as P
 from repro.core.engine import KnowledgeBase
 from repro.core.index import TypeIndex
 from repro.kernels import ops
-from repro.obs.metrics import REGISTRY
 
 INVALID = jnp.int32(np.iinfo(np.int32).max)
 
@@ -233,8 +232,6 @@ class QueryServer:
     def class_members(self, class_names):
         """Batch of Q1-style requests -> (distinct counts, member ids)."""
         self._sync()
-        REGISTRY.histogram("server/batch_size",
-                           kind="members").observe(len(class_names))
         ti, starts, lens, cap = self._ranges(class_names)
         counts, members = _serve_class_members(ti.subj, starts, lens, cap,
                                                self.topk)
@@ -243,8 +240,6 @@ class QueryServer:
     def class_prop_join(self, class_names, prop_names):
         """Batch of Q3-style requests -> (distinct-x counts, x bindings)."""
         self._sync()
-        REGISTRY.histogram("server/batch_size",
-                           kind="prop_join").observe(len(class_names))
         ti, starts, lens, cap = self._ranges(class_names)
         ps, pp = self._prop_view()
         plo, phi = self._intervals(prop_names, self.K.kb.tbox.properties)
@@ -439,8 +434,6 @@ class ShardedQueryServer:
     def class_members(self, class_names):
         """Batched Q1: fan out per shard, sum counts, merge member lists."""
         self._sync()
-        REGISTRY.histogram("server/batch_size",
-                           kind="members").observe(len(class_names))
         subj, starts, lens, cap = self._ranges(class_names)
         counts, members = self._fan_members(subj, starts, lens, cap)
         return (np.asarray(counts.sum(axis=0)),
@@ -449,8 +442,6 @@ class ShardedQueryServer:
     def class_prop_join(self, class_names, prop_names):
         """Batched Q3: the semi-join is fully shard-local (co-hashed x)."""
         self._sync()
-        REGISTRY.histogram("server/batch_size",
-                           kind="prop_join").observe(len(class_names))
         subj, starts, lens, cap = self._ranges(class_names)
         ps, pp = self._prop_views()
         plo, phi = self._intervals(prop_names, self.K.kb.tbox.properties)

@@ -58,11 +58,15 @@ is now a read-only dict view over it, keeping the PR-6 key set, and
 ``latency_stats`` is derived from the bounded ``serving/latency_s``
 histogram sketch (nothing in the runtime grows per-request anymore).
 Pass a :class:`~repro.obs.trace.Tracer` to record one span tree per
-request (queue wait, per-attempt pin / execute / backoff,
-stale-degradation events; batched members get ``batched=True`` +
-``batch_size`` attrs); ``Outcome.trace_id`` links the result back to its
-trace.  ``Outcome.latency_s`` splits into ``queue_s`` (admission-queue
-wait) + ``exec_s`` (service time); the two always sum to ``latency_s``.
+request (``serving.queue`` wait, per-attempt ``snapshot.pin`` /
+``serving.execute`` / ``serving.backoff``, the planner, engine and
+answer-build spans below them, stale-degradation events; batched members
+get ``batched=True`` + ``batch_size`` attrs); ``Outcome.trace_id`` links
+the result back to its trace.  Without one, requests admitted while a
+``jax.profiler`` trace is being captured are traced into
+:data:`~repro.obs.trace.PROFILE_TRACER`.  ``Outcome.latency_s`` splits
+into ``queue_s`` (admission-queue wait) + ``exec_s`` (service time); the
+two always sum to ``latency_s``.
 """
 from __future__ import annotations
 
@@ -135,7 +139,7 @@ class _Request:
     future: Future = field(default_factory=Future)
     dequeue_t: float | None = None
     trace: object = None  # obs_trace.Trace when the runtime traces
-    root: object = None  # the "request" root span
+    root: object = None  # the "serving.request" root span
     queue_span: object = None
 
 
@@ -415,18 +419,19 @@ class ServingRuntime:
         req.submitted_t = now
         req.deadline_t = None if deadline_s is None else now + deadline_s
         self.metrics.counter("serving/submitted").inc()
-        if self.tracer is not None:
-            req.trace = self.tracer.new_trace()
-            req.root = self.tracer.start_root(
-                req.trace, "request", n_patterns=len(req.patterns),
+        tracer = (self.tracer if self.tracer is not None
+                  else obs_trace.profile_tracer())
+        if tracer is not None:
+            req.trace = tracer.new_trace()
+            req.root = tracer.start_root(
+                req.trace, "serving.request", n_patterns=len(req.patterns),
                 mode=req.mode or "default", kind=req.kind)
-            req.queue_span = req.trace.new_span("queue", req.root.span_id, {})
+            req.queue_span = req.trace.new_span("serving.queue",
+                                                req.root.span_id, {})
         try:
             if self._queue.qsize() >= self.admission_bound:
                 raise queue.Full  # SLO-tightened soft bound: shed early
             self._queue.put_nowait(req)
-            self.metrics.gauge("serving/queue_depth").set(
-                self._queue.qsize())
         except queue.Full:
             # backpressure: reject at admission, before any execution cost
             lat = time.monotonic() - now
@@ -479,7 +484,7 @@ class ServingRuntime:
             req.root.set_attr(status=out.status, retries=out.retries,
                               stale=out.stale, version=out.version)
             req.root.finish()
-            self.tracer.finish_trace(req.trace)
+            req.trace.tracer.finish_trace(req.trace)
         req.future.set_result(out)
 
     def _jitter(self, attempt: int) -> float:
@@ -532,8 +537,6 @@ class ServingRuntime:
             if req is _STOP:
                 return
             batch, saw_stop = self._drain_batch(req)
-            self.metrics.gauge("serving/queue_depth").set(
-                self._queue.qsize())
             self._handle_batch(batch)
             if saw_stop:
                 return
@@ -587,16 +590,18 @@ class ServingRuntime:
         return ready
 
     def _member_spans(self, reqs, batch_size: int, version, stale):
-        """Open attempt/execute spans for every traced batch member."""
+        """Open ``serving.attempt`` / ``serving.execute`` spans for every
+        traced batch member."""
         spans = {}
         for r in reqs:
             if r.trace is None:
                 continue
             att = r.trace.new_span(
-                "attempt", r.root.span_id,
+                "serving.attempt", r.root.span_id,
                 {"attempt": 0, "batched": True, "batch_size": batch_size})
             ex = r.trace.new_span(
-                "execute", att.span_id, {"version": version, "stale": stale})
+                "serving.execute", att.span_id,
+                {"version": version, "stale": stale})
             spans[id(r)] = (att, ex)
         return spans
 
@@ -630,10 +635,14 @@ class ServingRuntime:
                 self._finish(r, self._outcome(r, "error", error=err))
             return
         spans = self._member_spans(ready, len(ready), pin.version, pin.stale)
+        # the shared dispatch's engine spans land once, under the first
+        # traced member's execute span
+        lead = next(iter(spans.values()), (None, None))[1]
         try:
             try:
-                results = pin.query_batch(
-                    [(r.patterns, r.select) for r in ready], mode=mode)
+                with obs_trace.activate(lead):
+                    results = pin.query_batch(
+                        [(r.patterns, r.select) for r in ready], mode=mode)
             except Exception:  # noqa: BLE001 — degrade, don't poison
                 self._close_member_spans(spans, fallback=True)
                 self.metrics.counter("serving/batch_fallback",
@@ -654,7 +663,9 @@ class ServingRuntime:
                     continue
                 answers = memo.get(id(rows))
                 if answers is None:
-                    answers = {tuple(t) for t in rows.tolist()}
+                    with obs_trace.activate(r.root), \
+                            obs_trace.span("serving.answers", rows=len(rows)):
+                        answers = {tuple(t) for t in rows.tolist()}
                     memo[id(rows)] = answers
                 self._finish(r, self._outcome(
                     r, "ok", answers=answers, version=pin.version,
@@ -762,7 +773,8 @@ class ServingRuntime:
         K was cut from.
         """
         rows, _ = pin.query(req.patterns, select=req.select, mode=req.mode)
-        ordered = sorted(map(tuple, rows.tolist()))
+        with obs_trace.span("serving.answers", rows=len(rows)):
+            ordered = sorted(map(tuple, rows.tolist()))
         ps = (req.page_size if req.page_size is not None
               else req.cursor.page_size)
         off = req.cursor.offset if req.cursor is not None else 0
@@ -783,8 +795,8 @@ class ServingRuntime:
                     req, "deadline", retries=retries,
                     error=None if last_err is None else
                     f"{type(last_err).__name__}: {last_err}")
-            with obs_trace.span("attempt", attempt=retries) as att:
-                with obs_trace.span("pin") as pin_sp:
+            with obs_trace.span("serving.attempt", attempt=retries) as att:
+                with obs_trace.span("snapshot.pin") as pin_sp:
                     pin, cursor_stale = self._pin_for(req)
                     stale = pin.stale or cursor_stale
                     pin_sp.set_attr(version=pin.version, stale=stale)
@@ -795,7 +807,7 @@ class ServingRuntime:
                                         version=pin.version)
                     paged = (req.page_size is not None
                              or req.cursor is not None)
-                    with obs_trace.span("execute", paginated=paged):
+                    with obs_trace.span("serving.execute", paginated=paged):
                         nxt = total = None
                         if paged:
                             answers, nxt, total = self._page(req, pin)
@@ -829,7 +841,7 @@ class ServingRuntime:
                         return self._outcome(
                             req, "deadline", retries=retries,
                             error=f"{type(e).__name__}: {e}")
-                    with obs_trace.span("backoff",
+                    with obs_trace.span("serving.backoff",
                                         delay_s=round(delay, 6)):
                         time.sleep(delay)
                 finally:
@@ -847,11 +859,12 @@ class ServingRuntime:
                     req, "deadline", retries=retries,
                     error=None if last_err is None else
                     f"{type(last_err).__name__}: {last_err}")
-            with obs_trace.span("attempt", attempt=retries,
+            with obs_trace.span("serving.attempt", attempt=retries,
                                 kind=req.kind) as att:
                 try:
                     faults.fire("serving.execute", attempt=retries)
-                    with obs_trace.span("execute", kind=req.kind) as ex:
+                    with obs_trace.span("serving.execute",
+                                        kind=req.kind) as ex:
                         counts, members, version = self._server_call(
                             req.kind, req.args)
                         ex.set_attr(version=version)
@@ -876,7 +889,7 @@ class ServingRuntime:
                         return self._outcome(
                             req, "deadline", retries=retries,
                             error=f"{type(e).__name__}: {e}")
-                    with obs_trace.span("backoff",
+                    with obs_trace.span("serving.backoff",
                                         delay_s=round(delay, 6)):
                         time.sleep(delay)
 

@@ -145,10 +145,10 @@ def test_registry_snapshot_is_json_ready():
 def test_span_tree_parenting_and_error_capture():
     tracer = Tracer()
     tr = tracer.new_trace()
-    root = tracer.start_root(tr, "request", mode="litemat")
+    root = tracer.start_root(tr, "serving.request", mode="litemat")
     with activate(root):
-        with obs_trace.span("pin", version=3):
-            with obs_trace.span("execute"):
+        with obs_trace.span("snapshot.pin", version=3):
+            with obs_trace.span("serving.execute"):
                 obs_trace.event("marker", k=1)
         with pytest.raises(ValueError):
             with obs_trace.span("boom"):
@@ -158,9 +158,10 @@ def test_span_tree_parenting_and_error_capture():
     d = tr.to_dict()
     assert validate_trace(d) == []
     by_name = {s["name"]: s for s in d["spans"]}
-    assert by_name["pin"]["parent_id"] == root.span_id
-    assert by_name["execute"]["parent_id"] == by_name["pin"]["span_id"]
-    assert by_name["execute"]["events"][0]["name"] == "marker"
+    assert by_name["snapshot.pin"]["parent_id"] == root.span_id
+    assert by_name["serving.execute"]["parent_id"] == \
+        by_name["snapshot.pin"]["span_id"]
+    assert by_name["serving.execute"]["events"][0]["name"] == "marker"
     assert "ValueError" in by_name["boom"]["attrs"]["error"]
     assert all(s["t1"] >= s["t0"] for s in d["spans"])
 
@@ -281,18 +282,19 @@ def test_every_request_yields_one_wellformed_trace(obs_kb):
         d = tr.to_dict()
         assert validate_trace(d) == [], d["trace_id"]
         root = d["spans"][0]
-        assert root["name"] == "request"
+        assert root["name"] == "serving.request"
         assert root["attrs"]["status"] == out.status
         assert root["attrs"]["retries"] == out.retries
         names = [s["name"] for s in d["spans"]]
-        assert "queue" in names
+        assert "serving.queue" in names
         if out.status == "shed":
             # rejected at admission: no execution spans ever open
-            assert "pin" not in names and "execute" not in names
+            assert "snapshot.pin" not in names
+            assert "serving.execute" not in names
         elif out.status == "ok":
-            assert "pin" in names and "execute" in names
-            assert len(tr.find("attempt")) == out.retries + 1
-            pin_attrs = tr.find("pin")[-1].attrs
+            assert "snapshot.pin" in names and "serving.execute" in names
+            assert len(tr.find("serving.attempt")) == out.retries + 1
+            pin_attrs = tr.find("snapshot.pin")[-1].attrs
             assert pin_attrs["version"] == out.version
             assert pin_attrs["stale"] == out.stale
         # (deadline_s=0.0 preempts before the first attempt: no pin span,
@@ -358,7 +360,7 @@ def test_shard_map_fallback_recorded_in_trace(lubm_kb):
 
     d = tr.to_dict()
     assert validate_trace(d) == []
-    dispatches = [s for s in d["spans"] if s["name"] == "shard_dispatch"]
+    dispatches = [s for s in d["spans"] if s["name"] == "shard.dispatch"]
     paths = [s["attrs"].get("path") for s in dispatches]
     assert "shard_map" in paths and "loop" in paths  # degraded mid-request
     sm = next(s for s in dispatches if s["attrs"]["path"] == "shard_map")
@@ -381,3 +383,156 @@ def test_snapshot_registry_stats_view(lubm_kb):
     finally:
         pin.release()
     assert reg.metrics.gauge_value("snapshot/pinned_refs") == 0
+
+
+# -- program spans on the profiler's clock ------------------------------------
+
+def _host_events(logdir) -> list:
+    """(name, start_ns, end_ns) of every host event in a profiler trace."""
+    from pathlib import Path
+
+    (path,) = Path(logdir).rglob("*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_program_span_is_a_host_event_nested_in_the_profiler_trace(tmp_path):
+    tracer = Tracer()
+    tr = tracer.new_trace()
+    root = tracer.start_root(tr, "serving.request")
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("outer"), activate(root):
+            with obs_trace.span("query.plan"):
+                with obs_trace.span("query.plan.count"):
+                    pass
+    tracer.finish_trace(tr)
+    ev = {n: (s, e) for n, s, e in _host_events(tmp_path)}
+    assert {"outer", "query.plan", "query.plan.count"} <= set(ev)
+    # the root was opened on no thread's stack: memory only
+    assert "serving.request" not in ev
+    for inner, outer in (("query.plan", "outer"),
+                         ("query.plan.count", "query.plan")):
+        assert ev[outer][0] <= ev[inner][0] <= ev[inner][1] <= ev[outer][1]
+
+
+def test_program_spans_keep_their_spacing_in_the_profiler_trace(tmp_path):
+    import gc
+    import time
+
+    tracer = Tracer()
+    tr = tracer.new_trace()
+    root = tracer.start_root(tr, "serving.request")
+    gc.disable()  # no collector pause between a span and its annotation
+    try:
+        with jax.profiler.trace(str(tmp_path)), activate(root):
+            with obs_trace.span("snapshot.pin"):
+                pass
+            time.sleep(0.05)
+            with obs_trace.span("query.plan"):
+                pass
+    finally:
+        gc.enable()
+    tracer.finish_trace(tr)
+    ev = {n: s for n, s, _ in _host_events(tmp_path)}
+    (pin,), (plan,) = tr.find("snapshot.pin"), tr.find("query.plan")
+    in_memory = plan.t0 - pin.t0
+    assert in_memory >= 0.05
+    assert abs((ev["query.plan"] - ev["snapshot.pin"]) * 1e-9
+               - in_memory) < 1e-3
+
+
+def test_span_builds_no_annotation_without_a_tracer(monkeypatch):
+    built = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            built.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", Counting)
+    assert obs_trace.span("query.plan") is obs_trace._NOOP
+    with obs_trace.span("query.plan"):
+        pass
+    assert built == []
+    tracer = Tracer()
+    tr = tracer.new_trace()
+    with activate(tracer.start_root(tr, "serving.request")):
+        with obs_trace.span("query.plan"):
+            pass
+    assert built == ["query.plan"]
+
+
+def test_runtime_traces_into_the_profile_tracer_while_profiling(
+        lubm_kb, tmp_path, monkeypatch):
+    K, _ = lubm_kb
+    monkeypatch.setattr(obs_trace, "PROFILE_TRACER", Tracer())
+    rt = ServingRuntime(K, modes=("litemat",), n_workers=1)
+    with rt:
+        assert rt.serve(Q1).trace_id is None  # no tracer, no profiler
+        with jax.profiler.trace(str(tmp_path)):
+            out = rt.serve(Q1)
+    (tr,) = obs_trace.PROFILE_TRACER.finished_traces()
+    assert out.trace_id == tr.trace_id and tr.root.name == "serving.request"
+    assert validate_trace(tr.to_dict()) == []
+    host = {n for n, _, _ in _host_events(tmp_path)}
+    assert {"serving.attempt", "snapshot.pin", "query.plan",
+            "query.dispatch", "serving.answers"} <= host
+
+
+def test_solo_request_spans_name_each_layer(lubm_kb):
+    """Parenting of the served path's spans, and one ``query.plan.count``
+    span and ``query/plan_syncs`` increment per planner round trip."""
+    K, _ = lubm_kb
+    tracer = Tracer()
+    syncs0 = REGISTRY.counter_value("query/plan_syncs")
+    rt = ServingRuntime(K, modes=("litemat",), n_workers=1,
+                        use_index=False, tracer=tracer)
+    with rt:
+        out = rt.serve(Q4)
+    assert out.ok
+    tr = _traces_by_id(tracer)[out.trace_id]
+    by_id = {s.span_id: s for s in tr.spans}
+
+    def parent(name):
+        return {by_id[s.parent_id].name for s in tr.find(name)}
+
+    assert parent("query.plan") == {"serving.execute"}
+    assert parent("query.plan.count") == {"query.plan"}
+    assert parent("query.execute") == {"serving.execute"}
+    for name in ("query.dispatch", "query.device_wait", "query.fetch"):
+        assert parent(name) == {"query.execute"}, name
+    assert parent("serving.answers") == {"serving.execute"}
+    counts = tr.find("query.plan.count")
+    assert counts  # the scan-only store plans every pattern by counting
+    assert REGISTRY.counter_value("query/plan_syncs") - syncs0 == len(counts)
+
+
+def test_served_executables_and_kernels_have_stable_names(lubm_kb):
+    from repro.kernels import stream_compact
+
+    K, _ = lubm_kb
+    eng = K.engine("litemat", use_index=False)
+    eng.run(Q1)
+    eng.run_batch([(Q4, None), (PAPER_QUERIES["Q4"], ("?x",))])
+    names = set()
+    for key, fn in eng._exec_cache.items():
+        fn = getattr(fn, "__wrapped__", fn)
+        names.add(fn.__name__)
+    assert {"query_exec", "plan_count"} <= names
+    planned = eng._plan(Q1, None)
+    sigs, dyns, caps, join_cap, sel, stores = planned[:6]
+    fn = eng._executable(("exec", eng.mode, sigs, tuple(caps), join_cap, sel),
+                         sigs, tuple(caps), join_cap, sel)
+    assert "@jit_query_exec" in fn.__wrapped__.lower(stores, dyns).as_text()
+    bfn = eng._batch_executable(("bexec", eng.mode, sigs, tuple(caps),
+                                 join_cap, sel, 2), sigs, tuple(caps),
+                                join_cap, sel)
+    stacked = jax.tree_util.tree_map(lambda x: jax.numpy.stack([x, x]), dyns)
+    assert "@jit_query_exec_batch" in \
+        bfn.__wrapped__.lower(stores, stacked).as_text()
+    mask = jax.numpy.zeros(2048, jax.numpy.int32)
+    jaxpr = jax.make_jaxpr(lambda m: stream_compact.stream_compact_pallas(
+        m, block=1024, interpret=True))(mask)
+    assert "name=stream_compact" in str(jaxpr)

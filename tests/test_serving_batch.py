@@ -77,7 +77,7 @@ def test_batch_members_carry_own_outcomes(lubm_kb):
     for o in outs:
         tr = by_id[o.trace_id]
         assert validate_trace(tr.to_dict()) == []
-        for sp in tr.find("attempt"):
+        for sp in tr.find("serving.attempt"):
             if sp.attrs.get("batched"):
                 saw_batched = True
                 assert sp.attrs["batch_size"] >= 2
@@ -284,7 +284,7 @@ def test_shed_trace_closes_queue_span(lubm_kb):
     for o in shed:
         tr = by_id[o.trace_id]
         assert validate_trace(tr.to_dict()) == []
-        (span,) = tr.find("queue")
+        (span,) = tr.find("serving.queue")
         assert span.t1 >= 0 and not span.attrs.get("dangling")
 
 
@@ -293,8 +293,8 @@ def test_validator_rejects_leaked_span():
     finish_trace is marked dangling and fails validation."""
     tracer = Tracer()
     tr = tracer.new_trace()
-    root = tracer.start_root(tr, "request")
-    tr.new_span("queue", root.span_id, {})  # never finished
+    root = tracer.start_root(tr, "serving.request")
+    tr.new_span("serving.queue", root.span_id, {})  # never finished
     tracer.finish_trace(tr)
     errors = validate_trace(tr.to_dict())
     assert any("leaked span" in e for e in errors)
