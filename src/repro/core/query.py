@@ -74,6 +74,7 @@ from repro.core.materialize import DeviceTBox
 from repro.kernels import ops
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import REGISTRY
+from repro.utils import pair64
 from repro.utils.parallel import run_concurrently
 
 INVALID = jnp.int32(np.iinfo(np.int32).max)
@@ -586,33 +587,56 @@ def _lower_scan(pvars, terms, extra, mode: str):
                       o_sig=o_sig, fused=fused), dyn
 
 
+def _shared_vars(a_vars, b_vars) -> list:
+    """Variables two relations share, in the build side's order."""
+    return [v for v in a_vars if v in b_vars]
+
+
+def _join_keys(shared, a_sorted: bool) -> list:
+    """The shared vars ``join`` sorts and searches on: the first two when
+    two or more are shared and it sorts the build side itself, else the
+    first alone."""
+    return shared[:2] if len(shared) >= 2 and not a_sorted else shared[:1]
+
+
 def join(a: Relation, b: Relation, cap: int, a_sorted: bool = False) -> Relation:
-    """Sort-merge equi-join on all shared vars (first var = sort key).
+    """Sort-merge equi-join on all shared vars.
+
+    With one shared var it is the sort and search key.  With two or more
+    the build side is sorted on the first two at once and each probe row
+    finds its match range with a pair search, so a cyclic pattern expands
+    only true matches on those two (a third and later var is verified on
+    the expanded rows) and ``total`` is the join's real size.
 
     ``a_sorted=True`` asserts the build side already sits in ascending
     ``shared[0]`` order with invalid rows last (the shard combine produces
-    exactly that via the device merge), skipping the argsort.
+    exactly that via the device merge), skipping the argsort; such a join
+    keys on ``shared[0]`` alone and verifies every later shared var.
     """
-    shared = [v for v in a.vars if v in b.vars]
+    shared = _shared_vars(a.vars, b.vars)
     if not shared:
         raise ValueError("cartesian products not supported — reorder the plan")
-    key = shared[0]
+    keys = _join_keys(shared, a_sorted)
 
-    # sort build side (a) by key; invalid rows sink
-    ka = jnp.where(a.valid, a.col(key), INVALID)
+    # sort build side (a) by its keys; invalid rows sink
+    ka = [jnp.where(a.valid, a.col(v), INVALID) for v in keys]
     if a_sorted:
         a_cols, ka_s = a.cols, ka
     else:
-        # unstable: rows of equal key may land in any order, which moves
+        # unstable: rows of equal keys may land in any order, which moves
         # rows within the join output but never changes its set
-        ka_s, aperm = lax.sort((ka, lax.iota(jnp.int32, ka.shape[0])),
-                               num_keys=1, is_stable=False)
+        *ka_s, aperm = lax.sort((*ka, lax.iota(jnp.int32, ka[0].shape[0])),
+                                num_keys=len(keys), is_stable=False)
         a_cols = a.cols[:, aperm]
 
-    kb_ = jnp.where(b.valid, b.col(key), INVALID)
-    L = jnp.searchsorted(ka_s, kb_, side="left")
-    R = jnp.searchsorted(ka_s, kb_, side="right")
-    counts = jnp.where(b.valid & (kb_ != INVALID), R - L, 0)
+    kb = [jnp.where(b.valid, b.col(v), INVALID) for v in keys]
+    if len(keys) == 1:
+        L = jnp.searchsorted(ka_s[0], kb[0], side="left")
+        R = jnp.searchsorted(ka_s[0], kb[0], side="right")
+    else:  # lax.sort's order on two keys is pair_less's signed (hi, lo)
+        L = pair64.searchsorted_pair(*ka_s, *kb, side="left")
+        R = pair64.searchsorted_pair(*ka_s, *kb, side="right")
+    counts = jnp.where(b.valid & (kb[0] != INVALID), R - L, 0)
     offsets = jnp.cumsum(counts)
     total = offsets[-1]
     starts = offsets - counts
@@ -622,13 +646,13 @@ def join(a: Relation, b: Relation, cap: int, a_sorted: bool = False) -> Relation
     probe = jnp.searchsorted(offsets, out_idx, side="right")
     probe_c = jnp.clip(probe, 0, counts.shape[0] - 1)
     rank = out_idx - starts[probe_c]
-    build_row = jnp.clip(L[probe_c] + rank, 0, ka_s.shape[0] - 1)
+    build_row = jnp.clip(L[probe_c] + rank, 0, ka_s[0].shape[0] - 1)
     ok = out_idx < jnp.minimum(total, cap)
 
-    # verify remaining shared vars
+    # verify the shared vars the keys left out
     a_g = a_cols[:, build_row]
     b_g = b.cols[:, probe_c]
-    for v in shared[1:]:
+    for v in shared[len(keys):]:
         ok = ok & (a_g[a.vars.index(v)] == b_g[b.vars.index(v)])
 
     out_vars = tuple(a.vars) + tuple(v for v in b.vars if v not in a.vars)
@@ -925,6 +949,26 @@ class QueryEngine:
         return query_exec
 
     @staticmethod
+    def _composite_joins(sigs) -> int:
+        """How many of the plan's sort-merge joins key on two shared vars.
+
+        Walks the relation's vars the way ``query_exec`` builds them: an
+        INL step or a join appends the pattern's new vars in order.
+        """
+        n, rel_vars = 0, None
+        for sig in sigs:
+            pv = tuple(dict.fromkeys(v for v in sig.pvars if v is not None))
+            if rel_vars is None:
+                rel_vars = pv
+                continue
+            if (sig.strategy != "inl"
+                    and len(_join_keys(_shared_vars(rel_vars, pv),
+                                       False)) == 2):
+                n += 1
+            rel_vars += tuple(v for v in pv if v not in rel_vars)
+        return n
+
+    @staticmethod
     def _timed_compile(fn, label: str):
         """Wrap a fresh jitted plan so its FIRST call — the one that pays
         trace+compile — is timed into ``query/compile_seconds{sig=}``.
@@ -1200,14 +1244,19 @@ class QueryEngine:
         with obs_trace.span("query.execute"):
             sigs, dyns, caps, join_cap, sel, stores, _, _, buckets = planned
             slabel = sig_label(sigs)
+            composite = self._composite_joins(sigs)
             for attempt in range(max_retries):
                 key = ("exec", self.mode, sigs, tuple(caps), join_cap, sel)
                 misses0 = self.cache_stats["misses"]
                 fn = self._executable(key, sigs, tuple(caps), join_cap, sel)
+                if composite:
+                    REGISTRY.counter("join/composite_key", site="query",
+                                     sig=slabel).inc(composite)
                 t0 = time.perf_counter()
                 cached = self.cache_stats["misses"] == misses0
                 with obs_trace.span("query.dispatch", cached=cached,
-                                    join_cap=join_cap) as dsp:
+                                    join_cap=join_cap,
+                                    composite_joins=composite) as dsp:
                     cols, valid, overflow, totals = fn(stores, dyns)
                 with obs_trace.span("query.device_wait"):
                     done = int(overflow) == 0
@@ -1314,14 +1363,19 @@ class QueryEngine:
             dyn_stack = jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs), *dyn_list)
             slabel = sig_label(sigs)
+            composite = self._composite_joins(sigs)
             for attempt in range(max_retries):
                 key = ("bexec", self.mode, sigs, tuple(caps), join_cap,
                        sel, Bp)
                 fn = self._batch_executable(key, sigs, tuple(caps),
                                             join_cap, sel)
+                if composite:
+                    REGISTRY.counter("join/composite_key", site="batch",
+                                     sig=slabel).inc(composite)
                 t0 = time.perf_counter()
                 with obs_trace.span("query.dispatch", join_cap=join_cap,
-                                    batch=B) as dsp:
+                                    batch=B,
+                                    composite_joins=composite) as dsp:
                     cols, valid, overflow, totals = fn(stores, dyn_stack)
                 with obs_trace.span("query.device_wait"):
                     ok = int(np.asarray(overflow)[:B].max()) == 0
